@@ -36,6 +36,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.obs.trace import TraceRecorder, set_tracer
 
 OUT = pathlib.Path("results/benchmarks")
@@ -67,11 +68,6 @@ def _phase_breakdown() -> dict | None:
             out[ev["name"]] = out.get(ev["name"], 0.0) + ev["dur"]
     return {k: round(v, 1) for k, v in out.items()} or None
 
-#: Every bench in this harness validates Pallas paths in interpret mode on
-#: CPU (the engine default); recorded in the metadata so a TPU baseline can
-#: never be gated against a CPU run.
-INTERPRET_MODE = True
-
 #: CLI workload knobs of the current invocation (set by ``main``), stamped
 #: into the metadata: a ``--fast`` or ``--backend``-narrowed run is a
 #: different workload and must never be gated against a full-run baseline.
@@ -88,12 +84,15 @@ def machine_meta() -> dict:
     import platform
 
     import jax
+
+    from repro.core.engine import interpret_mode
     dev = jax.devices()[0]
     return {
         "jax_version": jax.__version__,
         "platform": dev.platform,
         "device_kind": dev.device_kind,
-        "interpret_mode": INTERPRET_MODE,
+        # as the engine resolves it: a TPU baseline never gates a CPU run
+        "interpret_mode": interpret_mode(),
         # host identity: "cpu/cpu" is the same on every x86 box, so wall-time
         # gates additionally require the same hostname/core count — i.e. they
         # only ever fire on the machine that recorded the baseline.
@@ -126,7 +125,7 @@ def analysis_verdict() -> dict:
 
 
 def _emit(name: str, us_per_call: float, derived: str, payload: dict,
-          gate: dict | None = None):
+          gate: dict | None = None, meta: dict | None = None):
     """Print the CSV line and write the JSON record.
 
     ``gate`` optionally names a hardware-portable regression-gate metric,
@@ -134,11 +133,13 @@ def _emit(name: str, us_per_call: float, derived: str, payload: dict,
     ``--check`` prefers it over raw wall time.  Every record also carries the
     causality-linter verdict (``analysis`` key) so a perf baseline can never
     silently come from a tree that violates the protocol invariants.
+    ``meta`` overrides the machine metadata of benches measured elsewhere
+    (``_run_cpu_child``).
     """
     print(f"{name},{us_per_call:.1f},{derived}")
     OUT.mkdir(parents=True, exist_ok=True)
     payload = dict(payload, name=name, us_per_call=us_per_call,
-                   derived=derived, meta=machine_meta(),
+                   derived=derived, meta=meta or machine_meta(),
                    analysis=analysis_verdict())
     phases = _phase_breakdown()
     if phases is not None:
@@ -605,15 +606,26 @@ _COMM_SCRIPT = textwrap.dedent("""
 """)
 
 
-def bench_pdes_comm(fast=False, backend=None):
-    t0 = time.time()
-    env = dict(os.environ, PYTHONPATH="src")
-    script = _COMM_SCRIPT.replace("__BACKEND__", backend or "reference")
+def _run_cpu_child(script: str) -> tuple[dict, dict]:
+    """Run a fake-CPU-mesh bench script; returns (its RESULT, its metadata).
+
+    The child is pinned to the CPU: this process may hold the chip, and a
+    second process reaching for it would fail or hang.
+    """
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", script],
                        capture_output=True, text=True, env=env)
     assert r.returncode == 0, r.stderr[-2000:]
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][-1]
-    rec = json.loads(line[len("RESULT "):])
+    meta = dict(machine_meta(), platform="cpu", device_kind="cpu",
+                interpret_mode=True)
+    return json.loads(line[len("RESULT "):]), meta
+
+
+def bench_pdes_comm(fast=False, backend=None):
+    t0 = time.time()
+    script = _COMM_SCRIPT.replace("__BACKEND__", backend or "reference")
+    rec, meta = _run_cpu_child(script)
     ex = rec["exact_K16"]
     cv = rec["commavoid_K16"]
     msgs_ratio = ex["coll_msgs_per_step"] / max(cv["coll_msgs_per_step"], 1e-9)
@@ -623,7 +635,7 @@ def bench_pdes_comm(fast=False, backend=None):
           f"{cv['coll_msgs_per_step']:.2f} (x{msgs_ratio:.1f} fewer), "
           f"utilization cost {du:+.4f} at K=16, Δ=100", rec,
           gate={"metric": "msgs_reduction_commavoid_K16", "value": msgs_ratio,
-                "higher_is_better": True})
+                "higher_is_better": True}, meta=meta)
 
 
 # ---------------------------------------------------------------------------
@@ -692,13 +704,8 @@ def bench_window_sweep_sharded(fast=False):
     hardware-portable ratio.
     """
     t0 = time.time()
-    env = dict(os.environ, PYTHONPATH="src")
     script = _SWEEP_SHARDED_SCRIPT.replace("__FAST__", repr(bool(fast)))
-    r = subprocess.run([sys.executable, "-c", script],
-                       capture_output=True, text=True, env=env)
-    assert r.returncode == 0, r.stderr[-2000:]
-    line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][-1]
-    rec = json.loads(line[len("RESULT "):])
+    rec, meta = _run_cpu_child(script)
     speedup = rec["speedup_batched_vs_serial_sharded"]
     # as with bench_window_sweep: the bench only insists batching wins at
     # all; regression depth is the --check gate's job.
@@ -711,7 +718,7 @@ def bench_window_sweep_sharded(fast=False):
           f"replicas on a 2x4 mesh",
           rec,
           gate={"metric": "speedup_batched_vs_serial_sharded",
-                "value": speedup, "higher_is_better": True})
+                "value": speedup, "higher_is_better": True}, meta=meta)
 
 
 # ---------------------------------------------------------------------------
@@ -925,6 +932,7 @@ def main(argv=None) -> None:
                          "per-bench phases_us breakdown is recorded either "
                          "way)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     _RUN_CONFIG.update(fast=args.fast, cli_backend=args.backend)
     global _TRACER
     _TRACER = TraceRecorder()

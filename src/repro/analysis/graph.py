@@ -1,6 +1,6 @@
 """Jaxpr flattening: one dataflow graph across every nesting construct.
 
-``jax.make_jaxpr`` gives a *nested* program — ``pjit`` / ``scan`` / ``cond``
+``jax.make_jaxpr`` gives a *nested* program — ``jit`` / ``scan`` / ``cond``
 / ``shard_map`` / ``pallas_call`` equations each carry sub-jaxprs with their
 own variable namespaces.  The rules want plain dataflow questions ("does the
 tau output depend on a roll by 2", "is there a float psum on the tau path"),
@@ -9,7 +9,7 @@ so this module inlines everything into a single :class:`Graph` of
 
 Inlining semantics (what the rules rely on):
 
-* ``pjit`` / ``closed_call`` / ``custom_jvp_call`` / ``remat``: transparent —
+* ``jit`` / ``closed_call`` / ``custom_jvp_call`` / ``checkpoint``: transparent —
   the body is spliced in, provenance path extended with the jit name.
 * ``scan`` / ``while``: the body is inlined **once**.  Each carry component
   gets a synthetic ``scan_carry`` node (dep: the init value) whose
@@ -40,10 +40,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
-try:
-    from jax.extend.core import Literal
-except ImportError:  # older jax
-    from jax.core import Literal
+from jax.extend.core import Literal
 
 
 @dataclasses.dataclass
@@ -53,7 +50,7 @@ class Node:
     deps: list
     aval: Any = None          # output ShapedArray (or None)
     params: dict = dataclasses.field(default_factory=dict)
-    path: str = ""            # provenance: nesting path, e.g. "/pjit:one/scan"
+    path: str = ""            # provenance: nesting path, e.g. "/one/scan"
     src: str = ""             # best-effort source location "file:line"
 
     def describe(self) -> str:
@@ -173,9 +170,8 @@ class _Builder:
         params = dict(eqn.params)
         out_avals = [v.aval for v in eqn.outvars]
 
-        if name in ("pjit", "closed_call", "core_call", "xla_call",
-                    "remat", "checkpoint", "custom_jvp_call",
-                    "custom_vjp_call", "custom_vjp_call_jaxpr"):
+        if name in ("jit", "closed_call", "checkpoint", "custom_jvp_call",
+                    "custom_vjp_call"):
             sub = _sub_jaxpr(params, "jaxpr", "call_jaxpr", "fun_jaxpr")
             if sub is not None:
                 j, consts = _as_closed(sub)

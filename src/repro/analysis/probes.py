@@ -11,7 +11,7 @@ Probe shapes are chosen so that ring widths collide with no other extent
 sites), making the "which axis is the ring" lookup in ``graph.ring_axis_of``
 unambiguous.
 
-All tracing happens under ``jax.experimental.enable_x64`` — with 64-bit
+All tracing happens under ``compat.enable_x64`` — with 64-bit
 types *available*, any silent f32→f64 / i32→i64 promotion in the traced code
 materializes as a 64-bit aval, which is exactly what the dtype-drift rule
 scans for.  The clean tree is dtype-disciplined, so the graphs stay pure
@@ -63,7 +63,7 @@ class Probe:
 
 
 def _trace(fn, *args):
-    from jax.experimental import enable_x64
+    from ..compat import enable_x64
     with enable_x64():
         return build_graph(jax.make_jaxpr(fn)(*args))
 
@@ -75,9 +75,8 @@ def _single_probes(backend: str):
     for name, window in (("step", "exact"), ("stale", "stale")):
         if backend == "pallas_multistep" and window == "stale":
             continue       # rejected by EngineConfig: exact-GVT only
-        ecfg = EngineConfig(backend=backend, window=window, k_fuse=K,
-                            interpret=True)
-        advance = _make_advance(cfg, ecfg, B, L)
+        ecfg = EngineConfig(backend=backend, window=window, k_fuse=K)
+        advance = _make_advance(cfg, ecfg, B, L, interpret=True)
 
         def fn(tau, step0, seed, b0, advance=advance):
             return advance(tau, step0, seed, K, None, b0)
@@ -88,9 +87,8 @@ def _single_probes(backend: str):
                     ring_widths=frozenset({L, L + 2}), L_ring=L,
                     delta=cfg.delta, delta_input=None)
 
-    ecfg = EngineConfig(backend=backend, window="exact", k_fuse=K,
-                        interpret=True)
-    advance = _make_advance(cfg, ecfg, B, L)
+    ecfg = EngineConfig(backend=backend, window="exact", k_fuse=K)
+    advance = _make_advance(cfg, ecfg, B, L, interpret=True)
 
     def fn(tau, step0, seed, delta_col, b0, advance=advance):
         return advance(tau, step0, seed, K, delta_col, b0)
@@ -115,9 +113,8 @@ def _single_probes(backend: str):
         # production-shape trace: the VMEM rule sizes real BlockSpecs here
         Bp, Lp, Kp = 64, 1024, 16
         cfgp = PDESConfig(L=Lp, n_v=4, delta=DEFAULT_DELTA)
-        ecfg = EngineConfig(backend=backend, window="exact", k_fuse=Kp,
-                            interpret=True)
-        advance = _make_advance(cfgp, ecfg, Bp, Lp)
+        ecfg = EngineConfig(backend=backend, window="exact", k_fuse=Kp)
+        advance = _make_advance(cfgp, ecfg, Bp, Lp, interpret=True)
 
         def fn(tau, step0, seed, b0, advance=advance, Kp=Kp):
             return advance(tau, step0, seed, Kp, None, b0)
@@ -131,10 +128,7 @@ def _single_probes(backend: str):
 
 def _abstract_mesh(ens: int, ring: int):
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh((("data", ens), ("model", ring)))
-    except TypeError:      # older signature: axis_shapes, axis_names
-        return AbstractMesh((ens, ring), ("data", "model"))
+    return AbstractMesh((ens, ring), ("data", "model"))
 
 
 def _sharded_probes():

@@ -5,8 +5,10 @@ Each program instance of ``pdes_step`` / ``pdes_multistep`` /
 The footprint is fully static — block shapes x dtypes off the
 ``grid_mapping`` the call was traced with — so exceeding the budget is a
 compile-time fact, not a runtime surprise.  The default budget (16 MiB)
-matches a TPU core's VMEM; tune with ``--vmem-budget`` (the engine's own
-auto-tiler targets 8 MiB, leaving headroom for double buffering).
+matches a TPU v5e core's scoped VMEM; tune with ``--vmem-budget``.  This
+counts the blocks once and unpadded; the engine's auto-tiler
+(``kernels.tiling.vmem_bytes``) also counts padding, double buffering and
+body scratch.
 """
 from __future__ import annotations
 
@@ -27,6 +29,7 @@ def _block_bytes(bm) -> int:
         return 0
     n = 1
     for d in shape:
+        d = getattr(d, "block_size", d)      # pallas ``Blocked(n)`` dims
         n *= int(d) if isinstance(d, (int, np.integer)) else 1
     asd = getattr(bm, "array_shape_dtype", None)
     itemsize = np.dtype(getattr(asd, "dtype", np.float32)).itemsize

@@ -1,77 +1,34 @@
-"""Version-compatibility shims over drifting JAX APIs.
+"""The JAX calls whose spelling has drifted between releases, in one place.
 
-The codebase targets the newest JAX surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``lax.pcast``) but must also run on
-older installed versions where those names do not exist yet.  Every call
-site goes through this module so the fallbacks live in exactly one place:
+The repository pins the installed JAX (``pyproject.toml``); when it moves,
+only this module changes:
 
-* ``make_mesh(shape, axes)`` — passes explicit ``AxisType.Auto`` axis types
-  where the installed JAX supports them, and falls back to plain mesh axis
-  names (the pre-``AxisType`` behavior, semantically identical for every
-  mesh built here) otherwise.
-* ``shard_map(...)`` — prefers ``jax.shard_map``; falls back to
-  ``jax.experimental.shard_map.shard_map``.  ``check_rep`` is honored only
-  by the experimental API (the new API replaces it with varying-type
-  inference driven by ``pcast``).
-* ``pcast_varying(x, axes)`` — marks ``x`` as varying over ``axes`` for the
-  new shard_map type system; a no-op on versions without ``lax.pcast``
-  (their shard_map has no varying types, so there is nothing to mark —
-  pair it with ``check_rep=False`` when the carry changes replication).
+* ``make_mesh(shape, axes)`` — a mesh with explicit ``AxisType.Auto`` axes.
+* ``shard_map(...)`` — ``jax.shard_map``; ``check_rep`` is forwarded as the
+  current ``check_vma`` (replication/varying-type checking).
+* ``axis_size(name)`` — static mesh-axis size inside ``shard_map``.
+* ``enable_x64()`` — context in which 64-bit types are available.
 """
 from __future__ import annotations
 
 import jax
 
+axis_size = jax.lax.axis_size
+
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit Auto axis types when available."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool | None = None):
-    """``jax.shard_map`` when present, else the experimental implementation.
-
-    ``check_rep`` is forwarded under whichever spelling the installed
-    signature accepts (``check_rep``/``check_vma``); versions where
-    replication checking is always-on rely on ``pcast_varying`` instead.
-    """
-    import inspect
-    if hasattr(jax, "shard_map"):
-        impl = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-    kw = {}
-    if check_rep is not None:
-        params = inspect.signature(impl).parameters
-        for name in ("check_rep", "check_vma"):
-            if name in params:
-                kw[name] = check_rep
-                break
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
+    """``jax.shard_map`` with ``check_rep`` spelled as ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 
-def axis_size(axis_name):
-    """Static mesh-axis size inside shard_map (``lax.axis_size`` fallback).
-
-    ``lax.psum(1, name)`` is special-cased to constant-fold to the axis size
-    on versions predating ``lax.axis_size``.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def pcast_varying(x, axis_names):
-    """Mark ``x`` varying over ``axis_names`` (no-op without ``lax.pcast``)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axis_names, to="varying")
-    return x
+def enable_x64():
+    """Context manager: trace with 64-bit types available."""
+    return jax.enable_x64(True)

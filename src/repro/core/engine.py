@@ -94,14 +94,12 @@ class EngineConfig:
       k_fuse: steps per chunk — the multistep fuse depth, the stale-window
         refresh period, and the rebase cadence.
       block_b: ensemble rows per kernel tile (None = auto from VMEM budget).
-      interpret: run Pallas kernels in interpret mode (CPU validation).
     """
 
     backend: str = "reference"
     window: str = "exact"
     k_fuse: int = 16
     block_b: int | None = None
-    interpret: bool = True
 
     def __post_init__(self):
         if self.backend not in BACKENDS:
@@ -118,16 +116,27 @@ class EngineConfig:
                 "use backend='pallas' or 'reference' for window='stale'")
 
 
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: on every platform but TPU.
+
+    Host-side only.  Called under ``jit`` the answer would be baked into a
+    trace that is not keyed on it; the engine resolves it once per engine
+    and passes it to ``_run_single`` as a static argument.
+    """
+    return jax.default_backend() != "tpu"
+
+
 def _auto_block_b(B: int, L: int, block_b: int | None,
                   in_kernel_bits: bool = False) -> int:
-    """Kernel tile rows: shared VMEM model (kernels.tiling), divisor of B."""
+    """Kernel tile rows: shared VMEM model and tile rule (kernels.tiling)."""
     from ..kernels.tiling import pick_divisor_block, pick_vmem_block
     if block_b is None:
         return pick_vmem_block(B, L, in_kernel_bits=in_kernel_bits)
     return pick_divisor_block(B, block_b)
 
 
-def _make_advance(cfg: PDESConfig, ecfg: EngineConfig, B: int, L: int):
+def _make_advance(cfg: PDESConfig, ecfg: EngineConfig, B: int, L: int,
+                  interpret: bool):
     """Backend-specific K-step chunk advance.
 
     Returns ``advance(tau, step0, seed, k, delta_col, b0)`` ->
@@ -183,7 +192,7 @@ def _make_advance(cfg: PDESConfig, ecfg: EngineConfig, B: int, L: int):
                     ring_halo(tau), bits, gvt_eff,
                     n_v=cfg.n_v, delta=d, rd_mode=cfg.rd_mode,
                     border_both=cfg.border_both, block_b=bb,
-                    interpret=ecfg.interpret)
+                    interpret=interpret)
 
             return lax.scan(one, tau, step0 + jnp.arange(k, dtype=jnp.int32))
 
@@ -204,7 +213,7 @@ def _make_advance(cfg: PDESConfig, ecfg: EngineConfig, B: int, L: int):
                 tau, ctr, delta_col, trial_col, k_steps=k,
                 n_v=cfg.n_v, delta=cfg.delta, rd_mode=cfg.rd_mode,
                 border_both=cfg.border_both, block_b=bb,
-                interpret=ecfg.interpret)
+                interpret=interpret)
 
     else:  # pragma: no cover - sharded handled outside the single-device jit
         raise ValueError(ecfg.backend)
@@ -212,9 +221,11 @@ def _make_advance(cfg: PDESConfig, ecfg: EngineConfig, B: int, L: int):
     return advance
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "ecfg", "n_steps", "mode"))
+@functools.partial(jax.jit, static_argnames=("cfg", "ecfg", "n_steps", "mode",
+                                             "interpret"))
 def _run_single(state: SimState, seed, cfg: PDESConfig, ecfg: EngineConfig,
-                n_steps: int, mode: str, deltas=None, trial_base=0):
+                n_steps: int, mode: str, deltas=None, trial_base=0, *,
+                interpret: bool):
     """Shared chunked driver for the single-device backends.
 
     mode: "record" -> StepStats with leading (n_steps,) axis;
@@ -223,11 +234,12 @@ def _run_single(state: SimState, seed, cfg: PDESConfig, ecfg: EngineConfig,
     deltas: optional (B,) per-row window widths (sweep mode, see ``run``).
     trial_base: counter-stream trial coordinate — scalar index of row 0,
       or a (B,) vector of per-row global trial indices (see ``run``).
+    interpret: run the Pallas kernels in the interpreter (``interpret_mode``).
     """
     B, L = state.tau.shape
     K = max(1, min(ecfg.k_fuse, n_steps))
     n_chunks, rem = divmod(n_steps, K)
-    advance = _make_advance(cfg, ecfg, B, L)
+    advance = _make_advance(cfg, ecfg, B, L, interpret)
     dtype = state.tau.dtype
     delta_col = None if deltas is None else deltas.astype(dtype)[:, None]
     b0 = jnp.asarray(trial_base, jnp.int32)
@@ -289,7 +301,6 @@ class PDESEngine:
       window: "exact" | "stale" (see module docstring).
       k_fuse: chunk depth (fuse/refresh/rebase cadence).
       block_b: kernel tile rows (None = auto).
-      interpret: Pallas interpret mode (CPU validation).
       mesh / dist: required/optional for ``backend="sharded"`` — the device
         mesh and ``DistConfig``.  When ``dist`` is omitted it is derived
         from ``window`` (exact -> "exact", stale -> "commavoid" with
@@ -298,12 +309,12 @@ class PDESEngine:
 
     def __init__(self, cfg: PDESConfig, backend: str = "reference", *,
                  window: str = "exact", k_fuse: int = 16,
-                 block_b: int | None = None, interpret: bool = True,
-                 mesh=None, dist=None):
+                 block_b: int | None = None, mesh=None, dist=None):
         self.cfg = cfg
         self.ecfg = EngineConfig(backend=backend, window=window,
-                                 k_fuse=k_fuse, block_b=block_b,
-                                 interpret=interpret)
+                                 k_fuse=k_fuse, block_b=block_b)
+        #: compiled kernels on a TPU, the Pallas interpreter elsewhere
+        self.interpret = interpret_mode()
         self.mesh = mesh
         self.dist = dist
         if backend == "sharded":
@@ -399,7 +410,7 @@ class PDESEngine:
             return self._run_sharded(state, seed, n_steps, mode,
                                      deltas=deltas, trial_base=trial_base)
         return _run_single(state, seed, self.cfg, self.ecfg, n_steps, mode,
-                           deltas, trial_base)
+                           deltas, trial_base, interpret=self.interpret)
 
     def _run_sharded(self, state, seed, n_steps, mode, deltas=None,
                      trial_base=0):

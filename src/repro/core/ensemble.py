@@ -148,7 +148,7 @@ def steady_state_sweep(
     ``window``, ``k_fuse``, and (for ``backend="sharded"``) ``mesh`` /
     ``dist``, which route to ``experiments.sweep.run_window_sweep``'s mesh
     execution path.  ``steady_state``'s remaining engine options
-    (``block_b``/``interpret``: not spec-level) are rejected explicitly
+    (``block_b``: not spec-level) are rejected explicitly
     rather than silently dropped.
     """
     from ..experiments.sweep import WindowSweep, run_window_sweep

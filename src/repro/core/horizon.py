@@ -131,7 +131,9 @@ def decode_words(w0: jax.Array, w1: jax.Array, n_v: int, dtype):
     is_left = site == 0
     is_right = site == (n_v - 1)
     # uniform in (0, 1]: use the top 24 bits, then add 2^-25 to avoid log(0).
-    u = (w1 >> jnp.uint32(8)).astype(dtype) * 2.0**-24
+    # The 24-bit value fits int32 exactly; Mosaic has no uint32 -> f32 cast,
+    # so convert through int32 (same bits on every backend).
+    u = (w1 >> jnp.uint32(8)).astype(jnp.int32).astype(dtype) * 2.0**-24
     eta = -jnp.log(u + 2.0**-25)
     return is_left, is_right, eta
 
@@ -171,16 +173,15 @@ def conservative_update(
     Returns ``(tau_next, update)``.  Pure jnp — shared by the reference
     scan (``step_core``), the Pallas kernel bodies, and the sharded runtime.
     """
+    # boolean algebra rather than ``where(flag, ok, True)``: Mosaic cannot
+    # lower a select between boolean vectors.
     if rd_mode:
         causal_ok = jnp.ones(tau.shape, dtype=bool)
     elif border_both:
         is_border = is_left | is_right
-        ok = (tau <= left) & (tau <= right)
-        causal_ok = jnp.where(is_border, ok, True)
+        causal_ok = ~is_border | ((tau <= left) & (tau <= right))
     else:
-        ok_left = jnp.where(is_left, tau <= left, True)
-        ok_right = jnp.where(is_right, tau <= right, True)
-        causal_ok = ok_left & ok_right
+        causal_ok = (~is_left | (tau <= left)) & (~is_right | (tau <= right))
     if isinstance(delta, (int, float)) and math.isinf(delta):
         window_ok = jnp.ones(tau.shape, dtype=bool)
     else:

@@ -1,9 +1,8 @@
-"""Pallas TPU kernels for the PDES hot loop (validated in interpret mode on CPU)."""
+"""Pallas TPU kernels for the PDES hot loop (interpreted off the TPU)."""
 from .ops import (  # noqa: F401
     pdes_multistep,
     pdes_multistep_counter,
     pdes_step,
-    pick_block_b,
     ring_halo,
     simulate,
     step_ring,

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 
 from ..core import horizon
 from ..core.horizon import PDESConfig
-from . import tiling
 from .pdes_step import pdes_step
 from .pdes_multistep import pdes_multistep, pdes_multistep_counter  # noqa: F401  (re-export)
 
@@ -25,7 +24,7 @@ def ring_halo(tau: jax.Array) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret", "block_b"))
 def step_ring(tau: jax.Array, bits: jax.Array, cfg: PDESConfig,
-              *, interpret: bool = True, block_b: int = 8):
+              *, interpret: bool, block_b: int = 8):
     """One fused step on full rings via the one-step kernel.
 
     Computes the exact GVT outside the kernel (one XLA reduction), then does
@@ -42,7 +41,7 @@ def step_ring(tau: jax.Array, bits: jax.Array, cfg: PDESConfig,
 @functools.partial(jax.jit, static_argnames=("cfg", "n_steps", "interpret",
                                              "block_b", "k_fuse"))
 def simulate(state: horizon.SimState, key: jax.Array, cfg: PDESConfig,
-             n_steps: int, *, interpret: bool = True, block_b: int = 8,
+             n_steps: int, *, interpret: bool, block_b: int = 8,
              k_fuse: int = 16):
     """Kernel-path equivalent of ``horizon.run`` (exact algorithm).
 
@@ -89,20 +88,3 @@ def simulate(state: horizon.SimState, key: jax.Array, cfg: PDESConfig,
     out = {"u": cat(0), "w2": cat(1), "gvt": cat(2)}
     return horizon.SimState(tau, off, comp, step), out
 
-
-def vmem_bytes(cfg: PDESConfig, block_b: int, k_fuse: int = 1,
-               in_kernel_bits: bool = False) -> int:
-    """VMEM footprint estimate for tile-size selection (ops-level check).
-
-    Delegates to the shared model in ``kernels.tiling`` (one footprint
-    model for ops, kernels, and the engine); must stay well under ~16 MiB.
-    """
-    return tiling.vmem_bytes(cfg.L, block_b, in_kernel_bits=in_kernel_bits)
-
-
-def pick_block_b(cfg: PDESConfig, budget: int = 8 << 20) -> int:
-    """Largest power-of-two row block fitting the VMEM budget."""
-    bb = 16
-    while bb > 1 and vmem_bytes(cfg, bb) > budget:
-        bb //= 2
-    return bb

@@ -55,10 +55,10 @@ def _fused_step(tau, w0, w1, *, n_v, delta, rd_mode, border_both):
 def _write_step(tau_ref, stat_refs, tau_next, moments):
     tau_ref[...] = tau_next
     for key, ref in zip(STAT_KEYS, stat_refs):
-        ref[...] = moments[key][None, :]
+        ref[...] = moments[key][None, :, None]
 
 
-def _kernel_bits(tau_in_ref, bits_ref, tau_ref, *stat_refs,
+def _kernel_bits(tau_in_ref, w0_ref, w1_ref, tau_ref, *stat_refs,
                  n_v: int, delta: float, rd_mode: bool, border_both: bool):
     k = pl.program_id(1)
 
@@ -67,9 +67,8 @@ def _kernel_bits(tau_in_ref, bits_ref, tau_ref, *stat_refs,
         tau_ref[...] = tau_in_ref[...]
 
     tau = tau_ref[...]                      # (b, L) full rings
-    bits = bits_ref[0]                      # (b, L, 2) this step's events
     tau_next, moments = _fused_step(
-        tau, bits[..., 0], bits[..., 1],
+        tau, w0_ref[0], w1_ref[0],          # this step's (b, L) event words
         n_v=n_v, delta=delta, rd_mode=rd_mode, border_both=border_both)
     _write_step(tau_ref, stat_refs, tau_next, moments)
 
@@ -106,9 +105,11 @@ def _kernel_counter(ctr_ref, tau_in_ref, *refs,
 
 
 def _call_multistep(kern, inputs, in_specs, B, L, K, bb, dtype, interpret):
+    # per-step stats are written as (K, B, 1) columns: a (1, bb) row block
+    # of a (K, B) array breaks the TPU's (8, 128) block rule.
     out_shape = [jax.ShapeDtypeStruct((B, L), dtype)] + [
-        jax.ShapeDtypeStruct((K, B), dtype) for _ in STAT_KEYS]
-    row = pl.BlockSpec((1, bb), lambda i, k: (k, i))
+        jax.ShapeDtypeStruct((K, B, 1), dtype) for _ in STAT_KEYS]
+    row = pl.BlockSpec((1, bb, 1), lambda i, k: (k, i, 0))
     outs = pl.pallas_call(
         kern,
         grid=(B // bb, K),
@@ -118,7 +119,7 @@ def _call_multistep(kern, inputs, in_specs, B, L, K, bb, dtype, interpret):
         out_shape=out_shape,
         interpret=interpret,
     )(*inputs)
-    return outs[0], dict(zip(STAT_KEYS, outs[1:]))
+    return outs[0], {key: o[..., 0] for key, o in zip(STAT_KEYS, outs[1:])}
 
 
 @functools.partial(
@@ -135,7 +136,7 @@ def pdes_multistep(
     rd_mode: bool = False,
     border_both: bool = False,
     block_b: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """K fused exact-GVT PDES steps on full rings, bits streamed from HBM.
 
@@ -153,12 +154,11 @@ def pdes_multistep(
     bb = pick_divisor_block(B, block_b)
     kern = functools.partial(_kernel_bits, n_v=n_v, delta=delta,
                              rd_mode=rd_mode, border_both=border_both)
-    in_specs = [
-        pl.BlockSpec((bb, L), lambda i, k: (i, 0)),
-        pl.BlockSpec((1, bb, L, 2), lambda i, k: (k, i, 0, 0)),
-    ]
-    return _call_multistep(kern, (tau, bits), in_specs, B, L, K, bb,
-                           tau.dtype, interpret)
+    # the words travel as lane-dense (K, B, L) planes (see pdes_step)
+    plane = pl.BlockSpec((1, bb, L), lambda i, k: (k, i, 0))
+    in_specs = [pl.BlockSpec((bb, L), lambda i, k: (i, 0)), plane, plane]
+    return _call_multistep(kern, (tau, bits[..., 0], bits[..., 1]), in_specs,
+                           B, L, K, bb, tau.dtype, interpret)
 
 
 @functools.partial(
@@ -178,7 +178,7 @@ def pdes_multistep_counter(
     rd_mode: bool = False,
     border_both: bool = False,
     block_b: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """K fused exact-GVT steps with the event stream generated in-kernel.
 

@@ -23,7 +23,7 @@ Grid/tiling: grid is over ensemble-row blocks; each program instance owns a
 ``(block_b, Lc + 2)`` VMEM tile.  Row blocks are independent, so the grid is
 embarrassingly parallel ("parallel" dimension semantics).  The lane dimension
 (Lc) is kept whole per tile because the neighbor stencil couples the entire
-ring; VMEM budget is checked by the wrapper (ops.py).
+ring; the tile height comes from the VMEM model in ``tiling``.
 
 TPU note: on CPU we validate with ``interpret=True``; on real TPU hardware
 the uint32->exponential decode happens in VREGs and the kernel is purely
@@ -42,14 +42,13 @@ from ..core.horizon import (MOMENT_KEYS as STAT_KEYS, conservative_update,
 from .tiling import pick_divisor_block
 
 
-def _kernel(tau_ref, bits_ref, gvt_ref, out_ref, *stat_refs,
+def _kernel(tau_ref, w0_ref, w1_ref, gvt_ref, out_ref, *stat_refs,
             n_v: int, delta: float, rd_mode: bool, border_both: bool):
     tau_h = tau_ref[...]                      # (b, Lc + 2) haloed
     tau = tau_h[:, 1:-1]
-    bits = bits_ref[...]                      # (b, Lc, 2) uint32
 
     is_left, is_right, eta = decode_words(
-        bits[..., 0], bits[..., 1], n_v, out_ref.dtype)
+        w0_ref[...], w1_ref[...], n_v, out_ref.dtype)
     tau_next, update = conservative_update(
         tau, tau_h[:, :-2], tau_h[:, 2:], is_left, is_right, eta,
         gvt_ref[...],                         # (b, 1) broadcast window base
@@ -76,16 +75,19 @@ def pdes_step(
     rd_mode: bool = False,
     border_both: bool = False,
     block_b: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """One fused PDES step on a haloed chunk.
 
     Args:
       tau_haloed: (B, Lc + 2) local times with neighbor halo columns.
-      bits: (B, Lc, 2) uint32 event bits.
+      bits: (B, Lc, 2) uint32 event bits.  The kernel reads them as two
+        lane-dense (B, Lc) word planes: a trailing pair axis would sit on
+        the TPU's 128-lane axis and take 64x its size in VMEM.
       gvt: (B, 1) window base.
-      block_b: ensemble rows per VMEM tile.
-      interpret: run the kernel body in interpret mode (CPU validation).
+      block_b: ensemble rows per VMEM tile (see ``tiling.pick_divisor_block``).
+      interpret: run the kernel body in the Pallas interpreter (required off
+        the TPU; ``PDESEngine`` resolves it from the platform).
 
     Returns:
       (tau_next (B, Lc), stats dict of (B,): ucount/min/max/sum/sumsq/sumabs).
@@ -101,19 +103,16 @@ def pdes_step(
     out_shape = [jax.ShapeDtypeStruct((B, Lc), tau_haloed.dtype)] + [
         jax.ShapeDtypeStruct((B, 1), tau_haloed.dtype) for _ in STAT_KEYS]
     col = pl.BlockSpec((bb, 1), lambda i: (i, 0))
+    plane = pl.BlockSpec((bb, Lc), lambda i: (i, 0))
     outs = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, Lc2), lambda i: (i, 0)),
-            pl.BlockSpec((bb, Lc, 2), lambda i: (i, 0, 0)),
-            col,
-        ],
-        out_specs=[pl.BlockSpec((bb, Lc), lambda i: (i, 0))]
-        + [col] * len(STAT_KEYS),
+        in_specs=[pl.BlockSpec((bb, Lc2), lambda i: (i, 0)), plane, plane,
+                  col],
+        out_specs=[plane] + [col] * len(STAT_KEYS),
         out_shape=out_shape,
         interpret=interpret,
-    )(tau_haloed, bits, gvt)
+    )(tau_haloed, bits[..., 0], bits[..., 1], gvt)
     tau_next = outs[0]
     stats = {k: v[:, 0] for k, v in zip(STAT_KEYS, outs[1:])}
     return tau_next, stats

@@ -40,12 +40,18 @@ be applied *before* JAX loads — which is why this module parses arguments
 before importing the service and the package ``__init__`` is lazy.  If JAX
 is somehow already imported the flag fails loudly instead of silently
 no-opping.
+
+Compiled programs persist across runs in JAX's compilation cache:
+``JAX_COMPILATION_CACHE_DIR`` where set, else ``.jax_cache`` in the
+checkout (``repro.compile_cache``).
 """
 from __future__ import annotations
 
 import argparse
 import os
 import sys
+
+from .. import compile_cache
 
 
 def _parse_mesh(text: str) -> list[tuple[str, int]]:
@@ -174,6 +180,7 @@ def _main_drain(argv) -> int:
 
     if _apply_fake_devices(args):
         return 2
+    compile_cache.enable()
 
     # deferred so --fake-devices lands before the first JAX import
     from .wire import serve_queue
@@ -235,6 +242,7 @@ def _main_serve(argv) -> int:
 
     if _apply_fake_devices(args):
         return 2
+    compile_cache.enable()
 
     from .daemon import DaemonConfig, serve_daemon
     from .wire import DEFAULT_MAX_LINE_BYTES

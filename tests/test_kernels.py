@@ -8,7 +8,7 @@ import pytest
 
 from repro.core import horizon
 from repro.core.horizon import PDESConfig
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, tiling
 
 KEY = jax.random.key(7)
 
@@ -37,7 +37,8 @@ def test_pdes_step_matches_ref(L, n_v, delta, rd, B):
     state, bits = _state_and_bits(cfg, B)
     tau_h = ops.ring_halo(state.tau)
     gvt = jnp.min(state.tau, axis=-1, keepdims=True)
-    t1, s1 = ops.pdes_step(tau_h, bits, gvt, n_v=n_v, delta=delta, rd_mode=rd)
+    t1, s1 = ops.pdes_step(tau_h, bits, gvt, n_v=n_v, delta=delta, rd_mode=rd,
+                           interpret=True)
     t2, _, s2 = ref.pdes_step_ref(tau_h, bits, gvt, n_v=n_v, delta=delta,
                                   rd_mode=rd)
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
@@ -51,7 +52,7 @@ def test_pdes_step_matches_core(L, n_v, delta, rd, B):
     """Kernel path == horizon.step_core (the system's own semantics)."""
     cfg = PDESConfig(L=L, n_v=n_v, delta=delta, rd_mode=rd)
     state, bits = _state_and_bits(cfg, B)
-    t1, _ = ops.step_ring(state.tau, bits, cfg)
+    t1, _ = ops.step_ring(state.tau, bits, cfg, interpret=True)
     is_l, is_r, eta = horizon.decode_events(bits, cfg)
     t2, _, _ = horizon.step_core(state.tau, is_l, is_r, eta, cfg)
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
@@ -65,7 +66,7 @@ def test_pdes_multistep_matches_ref(L, n_v, delta, rd, B, K):
     bits = jnp.stack([horizon.event_bits(KEY, state.step + i, state.tau.shape)
                       for i in range(K)])
     t1, s1 = ops.pdes_multistep(state.tau, bits, n_v=n_v, delta=delta,
-                                rd_mode=rd)
+                                rd_mode=rd, interpret=True)
     t2, s2 = ref.pdes_multistep_ref(state.tau, bits, n_v=n_v, delta=delta,
                                     rd_mode=rd)
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
@@ -81,7 +82,8 @@ def test_pdes_multistep_counter_matches_ref(L, n_v, delta, rd, B):
     state, _ = _state_and_bits(cfg, B)
     ctr = jnp.array([[3, 5, 0, 0]], dtype=jnp.uint32)
     t1, s1 = ops.pdes_multistep_counter(state.tau, ctr, k_steps=6, n_v=n_v,
-                                        delta=delta, rd_mode=rd)
+                                        delta=delta, rd_mode=rd,
+                                        interpret=True)
     t2, s2 = ref.pdes_multistep_counter_ref(state.tau, ctr, k_steps=6,
                                             n_v=n_v, delta=delta, rd_mode=rd)
     np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
@@ -90,27 +92,31 @@ def test_pdes_multistep_counter_matches_ref(L, n_v, delta, rd, B):
                                    rtol=1e-6)
 
 
-@pytest.mark.parametrize("block_b", [1, 2, 8])
-def test_block_size_invariance(block_b):
+# tiles must be multiples of 8 rows (or the whole batch), so the grids
+# below split B = 64 rings into 1, 2 or 8 row blocks.
+@pytest.mark.parametrize("n_blocks", [1, 2, 8])
+def test_block_size_invariance(n_blocks):
     """Tiling must not change results."""
     cfg = PDESConfig(L=64, n_v=2, delta=4.0)
-    state, bits = _state_and_bits(cfg, 8)
-    ta, _ = ops.step_ring(state.tau, bits, cfg, block_b=8)
-    tb, _ = ops.step_ring(state.tau, bits, cfg, block_b=block_b)
+    state, bits = _state_and_bits(cfg, 64)
+    ta, _ = ops.step_ring(state.tau, bits, cfg, block_b=64, interpret=True)
+    tb, _ = ops.step_ring(state.tau, bits, cfg, block_b=64 // n_blocks,
+                          interpret=True)
     np.testing.assert_array_equal(np.asarray(ta), np.asarray(tb))
 
 
-@pytest.mark.parametrize("block_b", [1, 2, 8])
-def test_counter_kernel_block_invariance(block_b):
+@pytest.mark.parametrize("n_blocks", [1, 2, 8])
+def test_counter_kernel_block_invariance(n_blocks):
     """The counter kernel derives trial indices from program_id * block_b —
     tiling must not shift the event stream."""
     cfg = PDESConfig(L=64, n_v=2, delta=4.0)
-    state, _ = _state_and_bits(cfg, 8)
+    state, _ = _state_and_bits(cfg, 64)
     ctr = jnp.array([[11, 0, 4, 0]], dtype=jnp.uint32)   # nonzero b0 too
     ta, _ = ops.pdes_multistep_counter(state.tau, ctr, k_steps=4, n_v=2,
-                                       delta=4.0, block_b=8)
+                                       delta=4.0, block_b=64, interpret=True)
     tb, _ = ops.pdes_multistep_counter(state.tau, ctr, k_steps=4, n_v=2,
-                                       delta=4.0, block_b=block_b)
+                                       delta=4.0, block_b=64 // n_blocks,
+                                       interpret=True)
     np.testing.assert_array_equal(np.asarray(ta), np.asarray(tb))
 
 
@@ -121,7 +127,8 @@ def test_simulate_equals_run(n_steps, k_fuse):
     st0 = horizon.init_state(cfg, 8)
     key = jax.random.key(3)
     st_a, stats_a = horizon.run(st0, key, cfg, n_steps)
-    st_b, out_b = ops.simulate(st0, key, cfg, n_steps, k_fuse=k_fuse)
+    st_b, out_b = ops.simulate(st0, key, cfg, n_steps, k_fuse=k_fuse,
+                               interpret=True)
     np.testing.assert_allclose(np.asarray(stats_a.utilization),
                                np.asarray(out_b["u"]), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(stats_a.w2),
@@ -132,7 +139,22 @@ def test_simulate_equals_run(n_steps, k_fuse):
 
 
 def test_vmem_budget_helper():
-    cfg = PDESConfig(L=16384, n_v=1)
-    bb = ops.pick_block_b(cfg)
-    assert bb >= 1
-    assert ops.vmem_bytes(cfg, bb) <= 8 << 20
+    L, B = 16384, 1024
+    bb = tiling.pick_vmem_block(B, L)
+    assert bb % 8 == 0 and B % bb == 0
+    assert tiling.vmem_bytes(L, bb) <= tiling.SCOPED_VMEM_BYTES
+    assert tiling.vmem_bytes(L, 2 * bb) > tiling.SCOPED_VMEM_BYTES
+
+
+@pytest.mark.parametrize("B", [1, 3, 8, 12, 24, 40, 64, 100, 1280])
+@pytest.mark.parametrize("hint", [1, 4, 8, 16, 40, 4096])
+def test_tile_rule(B, hint):
+    """Every chosen tile divides B and is a multiple of 8 or B itself."""
+    bb = tiling.pick_divisor_block(B, hint)
+    assert B % bb == 0 and (bb % 8 == 0 or bb == B)
+    assert bb <= hint or bb == tiling.valid_tiles(B)[0]
+
+
+def test_no_valid_tile_is_a_clear_error():
+    with pytest.raises(ValueError, match=r"B=8 .*L=4194304.*budget"):
+        tiling.pick_vmem_block(8, 1 << 22)
