@@ -25,7 +25,6 @@ present" in a snapshot means the instrumented path actually ran.
 Exposition:
 
 * :func:`MetricsRegistry.snapshot` — JSON-ready dict of every series;
-* :func:`append_jsonl` — the JSONL metrics sink (one snapshot per line);
 * :func:`to_prometheus` — Prometheus text exposition format;
 * :func:`write_snapshot` — atomic ``metrics.json`` + ``metrics.prom`` pair
   in a directory, written with the same tmp+rename+fsync discipline as
@@ -40,8 +39,8 @@ import os
 import time
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "DEFAULT_BUCKETS", "to_prometheus", "append_jsonl",
-           "write_snapshot", "SNAPSHOT_BASENAME", "PROM_BASENAME"]
+           "DEFAULT_BUCKETS", "to_prometheus", "write_snapshot",
+           "SNAPSHOT_BASENAME", "PROM_BASENAME"]
 
 #: default histogram bucket upper bounds (seconds-flavored, Prometheus-ish);
 #: instruments measuring ratios or physics quantities pass their own.
@@ -282,20 +281,6 @@ def to_prometheus(registry: MetricsRegistry) -> str:
             else:
                 lines.append(f"{m.name}{lbl(k)} {_fmt(v)}")
     return "\n".join(lines) + ("\n" if lines else "")
-
-
-def append_jsonl(registry: MetricsRegistry, path) -> dict:
-    """Append one snapshot line to a JSONL metrics sink; returns it.
-
-    The flat-file cousin of a scrape: every call adds a timestamped
-    snapshot, so per-round rates fall out of adjacent-line differences
-    (``python -m repro.obs summarize`` reads the last line).
-    """
-    snap = registry.snapshot()
-    with open(path, "a") as fh:
-        fh.write(json.dumps(snap) + "\n")
-        fh.flush()
-    return snap
 
 
 def write_snapshot(registry: MetricsRegistry, directory) -> dict:

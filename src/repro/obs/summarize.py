@@ -1,10 +1,9 @@
 """Render and validate metrics snapshots and Chrome traces.
 
 ``python -m repro.obs summarize [--check] PATH...`` turns the files the
-telemetry layer writes — ``metrics.json`` / ``metrics.prom`` snapshot
-dirs, JSONL metric sinks, Chrome-trace JSONs — into the human text table
-the service CLI's one-line summary approximates, and (with ``--check``)
-validates them for CI:
+telemetry layer writes — ``metrics.json`` snapshot dirs and Chrome-trace
+JSONs — into the human text table the service CLI's one-line summary
+approximates, and (with ``--check``) validates them for CI:
 
 * a metrics snapshot must be non-empty, and if it came from the sweep
   service (any ``repro_service_*`` series) it must contain live paper
@@ -15,8 +14,7 @@ validates them for CI:
   a corrupt trace).
 
 File kind is sniffed from content, not extension: a dict with
-``traceEvents`` is a trace, one with ``series`` is a metrics snapshot, a
-JSONL file is a sink (its last line is summarized).
+``traceEvents`` is a trace, one with ``series`` is a metrics snapshot.
 """
 from __future__ import annotations
 
@@ -39,25 +37,16 @@ REQUIRED_SERVICE_SERIES = (
 def load_any(path) -> tuple[str, dict]:
     """Load a telemetry file, returning ``(kind, obj)``.
 
-    ``kind`` is ``"trace"`` or ``"metrics"``.  JSONL sinks yield their
-    last snapshot line.  A directory is resolved to its ``metrics.json``.
-    Raises ValueError on unrecognized content.
+    ``kind`` is ``"trace"`` or ``"metrics"``.  A directory is resolved to
+    its ``metrics.json``.  Raises ValueError on unrecognized content.
     """
     if os.path.isdir(path):
         path = os.path.join(path, "metrics.json")
     with open(path) as fh:
         text = fh.read()
-    lines = [ln for ln in text.splitlines() if ln.strip()]
-    if not lines:
+    if not text.strip():
         raise ValueError(f"{path}: empty file")
-    if len(lines) > 1 and not text.lstrip().startswith("{\n") \
-            and all(ln.lstrip().startswith("{") for ln in lines):
-        try:
-            obj = json.loads(lines[-1])
-        except json.JSONDecodeError:
-            obj = json.loads(text)
-    else:
-        obj = json.loads(text)
+    obj = json.loads(text)
     if not isinstance(obj, dict):
         raise ValueError(f"{path}: expected a JSON object")
     if "traceEvents" in obj:
@@ -201,13 +190,12 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="repro.obs",
         description="summarize/validate telemetry files "
-                    "(metrics snapshots, JSONL sinks, Chrome traces)")
+                    "(metrics snapshots, Chrome traces)")
     sub = ap.add_subparsers(dest="cmd", required=True)
     sm = sub.add_parser("summarize",
                         help="render telemetry files as text tables")
     sm.add_argument("paths", nargs="+",
-                    help="metrics.json / metrics dir / sink.jsonl / "
-                         "trace.json")
+                    help="metrics.json / metrics dir / trace.json")
     sm.add_argument("--check", action="store_true",
                     help="validate instead of merely rendering: non-empty,"
                          " required service series present, spans nest")

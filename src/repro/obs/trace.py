@@ -1,10 +1,16 @@
-"""Span tracing: Chrome-trace/Perfetto JSON emission with an ambient tracer.
+"""Span tracing: one span API, two sinks, and an ambient tracer.
 
-A :class:`TraceRecorder` collects completed spans as Chrome trace events
-(``ph: "X"`` — complete events with microsecond ``ts``/``dur``) that load
-directly into ``chrome://tracing`` / Perfetto.  The clock and pid are
-injectable so golden-file tests can produce byte-stable traces; production
-callers take the defaults (``time.perf_counter``, real pid).
+Two recorders take the same spans:
+
+* :class:`TraceRecorder` collects completed spans as Chrome trace events
+  (``ph: "X"`` — complete events with microsecond ``ts``/``dur``) that load
+  directly into ``chrome://tracing`` / Perfetto.  The clock and pid are
+  injectable so golden-file tests can produce byte-stable traces;
+  production callers take the defaults (``time.perf_counter``, real pid).
+* :class:`ProfilerRecorder` turns each span into a
+  ``jax.profiler.TraceAnnotation`` of the same name, so that inside a
+  ``jax.profiler`` session it lands on the host plane of the same
+  ``.xplane.pb`` as the device's ops, on the device trace's clock.
 
 Instrumented library code does not thread a recorder through every call —
 it asks for the process-ambient tracer::
@@ -19,7 +25,8 @@ with ``None``) the :func:`span` helper is a no-op costing one dict lookup,
 so the hot path stays clean for ordinary library users.  The harnesses
 that want a trace (``benchmarks/run.py --trace``, the service daemon,
 ``python -m repro.service --trace``) install a recorder around their run
-and :meth:`TraceRecorder.save` it at exit.
+and :meth:`TraceRecorder.save` it at exit; a caller that profiles the
+device installs a :class:`ProfilerRecorder` for the profiled window.
 
 Spans are strictly nested per thread (enter/exit discipline of ``with``),
 which is exactly what ``repro.obs.summarize --check`` verifies on the
@@ -35,7 +42,8 @@ import os
 import threading
 import time
 
-__all__ = ["TraceRecorder", "Span", "set_tracer", "current_tracer", "span"]
+__all__ = ["TraceRecorder", "ProfilerRecorder", "Span", "set_tracer",
+           "current_tracer", "span"]
 
 
 class Span:
@@ -139,6 +147,45 @@ class TraceRecorder:
         os.replace(tmp, path)
 
 
+class ProfilerRecorder:
+    """Spans as ``jax.profiler`` host events, on the device trace's clock.
+
+    Each span enters a ``jax.profiler.TraceAnnotation`` of the same name:
+    inside a profiler session (``jax.profiler.start_trace``) it is recorded
+    on the ``/host:CPU`` plane of the trace that also holds the device's
+    ops; outside one it records nothing.  The yielded :class:`Span` takes
+    ``args`` like :class:`TraceRecorder`'s, but they are not written: the
+    profiler would fold them into the event's name (``name#k=v#``), and a
+    trace's readers select spans by name.  JAX is imported on construction,
+    so ``repro.obs`` itself stays importable without it.
+    """
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+
+    def span(self, name: str, cat: str = "repro", args: dict | None = None):
+        """Context manager annotating its body as a host event ``name``."""
+        return _AnnotationCtx(self._annotation(name),
+                              Span(name, cat, dict(args or {})))
+
+
+class _AnnotationCtx:
+    __slots__ = ("_annotation", "_span")
+
+    def __init__(self, annotation, s: Span):
+        self._annotation = annotation
+        self._span = s
+
+    def __enter__(self) -> Span:
+        self._annotation.__enter__()
+        return self._span
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
 class _SpanCtx:
     __slots__ = ("_rec", "_span")
 
@@ -168,15 +215,18 @@ class _NullSpanCtx:
 
 
 _NULL = _NullSpanCtx()
-_ambient: TraceRecorder | None = None
+_ambient: TraceRecorder | ProfilerRecorder | None = None
 
 
-def set_tracer(tracer: TraceRecorder | None) -> TraceRecorder | None:
+def set_tracer(tracer: TraceRecorder | ProfilerRecorder | None
+               ) -> TraceRecorder | ProfilerRecorder | None:
     """Install the process-ambient tracer; returns the previous one.
 
     Harness-level API: the benchmark runner and the service CLI install a
     recorder around their run and restore the previous value after, so a
-    library call tree needs no tracer plumbing.
+    library call tree needs no tracer plumbing.  What is installed picks
+    the sink: a :class:`TraceRecorder` for Chrome JSON, a
+    :class:`ProfilerRecorder` for the device profiler's trace.
     """
     global _ambient
     prev = _ambient
@@ -184,7 +234,7 @@ def set_tracer(tracer: TraceRecorder | None) -> TraceRecorder | None:
     return prev
 
 
-def current_tracer() -> TraceRecorder | None:
+def current_tracer() -> TraceRecorder | ProfilerRecorder | None:
     """The installed ambient tracer, or None."""
     return _ambient
 

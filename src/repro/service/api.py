@@ -22,12 +22,12 @@ SweepResult`\\ s, multiplexing compatible requests into shared device passes:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import json
 import math
 import time
-from contextlib import nullcontext
 
 import numpy as np
 
@@ -37,11 +37,39 @@ from ..core.horizon import PDESConfig, SimState, StepStats
 from ..experiments.sweep import (SweepRecord, SweepResult, WindowSweep,
                                  _derive_dist, _round_up, plan_mesh_sweep,
                                  spec_to_dict)
+from ..obs import trace as obs_trace
 from .scheduler import BatchScheduler, CompatKey, GridJob, PackedPass
 from .state_cache import StateCache
 
 __all__ = ["SweepRequest", "SweepResponse", "ServiceStats", "SweepService",
            "canonicalize_spec", "spec_fingerprint"]
+
+#: the event JAX reports once per jaxpr trace, i.e. per jit cache miss
+JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_n_jaxpr_traces = 0
+_listening = False
+
+
+def _on_jax_duration(event: str, duration: float, **kwargs) -> None:
+    global _n_jaxpr_traces
+    if event == JAXPR_TRACE_EVENT:
+        _n_jaxpr_traces += 1
+
+
+def _jaxpr_traces() -> int:
+    """Jaxpr traces JAX has reported in this process since the first call.
+
+    One ``jax.monitoring`` listener serves the whole process; it is
+    registered on the first call.  The service reads it around each pass
+    (``ServiceStats.n_traces``): a pass whose shapes were seen before
+    should trace nothing.
+    """
+    global _listening
+    if not _listening:
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _listening = True
+    return _n_jaxpr_traces
 
 
 def canonicalize_spec(spec: WindowSweep) -> WindowSweep:
@@ -130,6 +158,7 @@ class ServiceStats:
     state_cache_hits: int = 0     # mirrors StateCache counters (hit/miss/
     state_cache_misses: int = 0   # eviction) so cache thrash under max_rows
     state_cache_evictions: int = 0  # pressure is visible in every summary
+    n_traces: int = 0             # jaxpr traces JAX reported while passes ran
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -232,6 +261,9 @@ class _ServiceInstruments:
             "state_cache_evictions": c(
                 "repro_service_state_cache_evictions",
                 "burned-state cache rows evicted (max_rows pressure)"),
+            "n_traces": c("repro_service_jaxpr_traces",
+                          "jaxpr traces (jit cache misses) while passes "
+                          "ran"),
         }
         self.fairness_throttles = c(
             "repro_service_fairness_throttles",
@@ -252,8 +284,20 @@ class _ServiceInstruments:
                                   "burned rows currently cached",
                                   unit="rows")
         self.phase_seconds = h("repro_service_phase_seconds",
-                               "service step phases: schedule (take) and "
-                               "engine (pass execution)", unit="s")
+                               "service phases, labelled with their span's "
+                               "name (service.schedule, pass.burn, ...)",
+                               unit="s")
+
+
+@contextlib.contextmanager
+def _timed(span, histogram, name: str):
+    """``span``, with its seconds observed into ``histogram`` on exit."""
+    t0 = time.perf_counter()
+    try:
+        with span as sp:
+            yield sp
+    finally:
+        histogram.observe(time.perf_counter() - t0, phase=name)
 
 
 class SweepService:
@@ -275,10 +319,16 @@ class SweepService:
       telemetry: an optional :class:`repro.obs.Telemetry` bundle.  When
         set, the service mirrors its stats into live metrics, observes the
         paper observables (⟨u⟩, ⟨w²⟩, GVT rate, window occupancy) per
-        pass, and — if the bundle carries a tracer — emits one span per
-        :class:`~.scheduler.PackedPass` annotated with the CompatKey, row
-        counts, and cache provenance.  Strictly off-path: responses are
-        bit-identical with or without it.
+        pass, and times each phase into ``repro_service_phase_seconds``.
+        Strictly off-path: responses are bit-identical with or without it.
+
+    Spans: every phase of a round is a span (``service.schedule``, one
+    ``pass`` per :class:`~.scheduler.PackedPass` annotated with the
+    CompatKey, row counts and cache provenance, the ``pass.*`` phases
+    inside it, ``service.flush``), recorded by the telemetry bundle's
+    tracer if it has one, else by the ambient tracer
+    (``repro.obs.set_tracer``), else by nothing.  No span syncs the
+    device: a span around a host read of device data covers the wait.
 
     ``submit`` registers a request; ``step`` runs one scheduling round;
     ``drain`` forces everything through and returns responses in
@@ -323,6 +373,24 @@ class SweepService:
         self.telemetry = telemetry
         self._ins = (None if telemetry is None
                      else _ServiceInstruments(telemetry.registry))
+
+    def _phase(self, name: str, args=None):
+        """A span ``name`` around one phase, timed into the phase metrics.
+
+        The span goes to the telemetry bundle's tracer, else to the ambient
+        one; ``args`` (a callable giving the span's arguments) is called
+        only when one of them records.  With telemetry attached, the
+        phase's seconds are observed under ``phase=name``.  With neither,
+        this is the null span.
+        """
+        tel = self.telemetry
+        tracer = tel.tracer if tel is not None and tel.tracer is not None \
+            else obs_trace.current_tracer()
+        span = obs_trace._NULL if tracer is None else tracer.span(
+            name, cat="service", args=args() if args else None)
+        if self._ins is None:
+            return span
+        return _timed(span, self._ins.phase_seconds, name)
 
     # -- request intake ----------------------------------------------------
 
@@ -397,20 +465,13 @@ class SweepService:
         is the laggard among active tenants, so a requester who went idle
         can never permanently block the window for everyone still queued.
         """
-        ins = self._ins
-        t0 = time.perf_counter() if ins is not None else 0.0
-        active = self.scheduler.pending_requesters
-        served = {r: n for r, n in self._served_rows.items() if r in active}
-        passes = self.scheduler.take(served, force=force)
-        if ins is not None:
-            ins.phase_seconds.observe(time.perf_counter() - t0,
-                                      phase="schedule")
-            t0 = time.perf_counter()
+        with self._phase("service.schedule"):
+            active = self.scheduler.pending_requesters
+            served = {r: n for r, n in self._served_rows.items()
+                      if r in active}
+            passes = self.scheduler.take(served, force=force)
         for p in passes:
             self._run_pass(p)
-        if ins is not None and passes:
-            ins.phase_seconds.observe(time.perf_counter() - t0,
-                                      phase="engine")
         self._sync_cache_stats()
         self._sync_metrics()
         return len(passes)
@@ -420,6 +481,7 @@ class SweepService:
         fail the pass's requests (structured ``engine`` error responses)
         instead of propagating — one bad pass never poisons the drain."""
         delay = self.retry_base_s
+        traces = _jaxpr_traces()
         for attempt in range(self.engine_retries + 1):
             try:
                 self._execute(p)
@@ -431,6 +493,7 @@ class SweepService:
                 self.stats.n_retries += 1
                 time.sleep(min(delay, self.retry_cap_s))
                 delay *= 2
+        self.stats.n_traces += _jaxpr_traces() - traces
         self.flush_ready()
 
     def _fail_pass(self, p: PackedPass, exc: Exception) -> None:
@@ -476,17 +539,18 @@ class SweepService:
         if self.on_response is None:
             return 0
         emitted = 0
-        for rid in list(self._order):
-            if rid not in self._pending:
-                continue
-            resp = self._response_for(rid)
-            if resp is None:
-                continue
-            del self._pending[rid]
-            if resp.error is not None:
-                self.stats.n_errors += 1
-            self.on_response(resp)
-            emitted += 1
+        with self._phase("service.flush"):
+            for rid in list(self._order):
+                if rid not in self._pending:
+                    continue
+                resp = self._response_for(rid)
+                if resp is None:
+                    continue
+                del self._pending[rid]
+                if resp.error is not None:
+                    self.stats.n_errors += 1
+                self.on_response(resp)
+                emitted += 1
         if emitted:
             self._order = [r for r in self._order if r in self._pending]
         return emitted
@@ -583,43 +647,47 @@ class SweepService:
         drows = jnp.asarray(deltas)
         tvec = jnp.asarray(trials)
 
-        ctx = nullcontext() if self.telemetry is None else \
-            self.telemetry.spans("pass", cat="service", args=dict(
+        with self._phase("pass", lambda: dict(
                 dataclasses.asdict(key), n_rows=B, n_pad=n_pad,
                 n_jobs=len(p.jobs),
-                requesters=sorted({j.requester for j in p.jobs})))
-        with ctx as sp:
+                requesters=sorted({j.requester for j in p.jobs}))) as sp:
             pre_cached = self.stats.rows_from_state_cache
             pre_burned = self.stats.rows_burned
-            state = self._burned_state(eng, key, p.rows, n_pad, drows, tvec)
-            _, stats = eng.run(state, key.seed, key.n_steps, deltas=drows,
-                               trial_base=tvec)
+            state = self._burned_state(eng, key, p.rows, n_pad, deltas,
+                                       trials)
+            with self._phase("pass.measure"):
+                _, stats = eng.run(state, key.seed, key.n_steps,
+                                   deltas=drows, trial_base=tvec)
             self.stats.n_passes += 1
             self.stats.n_engine_calls += 1
             self.stats.rows_computed += B
             self.stats.engine_row_steps += (B + n_pad) * key.n_steps
 
-            arrs = StepStats(*(np.asarray(a)[:, :B] for a in stats))
+            with self._phase("pass.stats.fetch"):
+                arrs = StepStats(*(np.asarray(a)[:, :B] for a in stats))
             if sp is not None:
                 sp.args.update(
                     rows_from_cache=(self.stats.rows_from_state_cache
                                      - pre_cached),
                     rows_burned=self.stats.rows_burned - pre_burned)
-            if self._ins is not None:
-                self._observe_pass(p, arrs, deltas[:B])
-            for job, cols in zip(p.jobs, p.cols):
-                idx = np.asarray(cols, np.intp)
-                # fancy indexing yields F-ordered columns; numpy's axis-0
-                # mean sums in a layout-dependent order, so restore C order
-                # to keep the reduction bit-identical to a direct (T, B) run
-                sliced = StepStats(*(np.ascontiguousarray(a[:, idx])
-                                     for a in arrs))
-                red = measurement.sweep_reduce(
-                    sliced, len(job.deltas), job.replicas,
-                    steady_frac=job.steady_frac)
-                self._served_rows[job.requester] = (
-                    self._served_rows.get(job.requester, 0) + len(job.rows))
-                self._finish_job(job, red)
+            with self._phase("pass.stats.reduce"):
+                if self._ins is not None:
+                    self._observe_pass(p, arrs, deltas[:B])
+                for job, cols in zip(p.jobs, p.cols):
+                    idx = np.asarray(cols, np.intp)
+                    # fancy indexing yields F-ordered columns; numpy's
+                    # axis-0 mean sums in a layout-dependent order, so
+                    # restore C order to keep the reduction bit-identical
+                    # to a direct (T, B) run
+                    sliced = StepStats(*(np.ascontiguousarray(a[:, idx])
+                                         for a in arrs))
+                    red = measurement.sweep_reduce(
+                        sliced, len(job.deltas), job.replicas,
+                        steady_frac=job.steady_frac)
+                    self._served_rows[job.requester] = (
+                        self._served_rows.get(job.requester, 0)
+                        + len(job.rows))
+                    self._finish_job(job, red)
 
     def _observe_pass(self, p: PackedPass, arrs: StepStats,
                       deltas: np.ndarray) -> None:
@@ -645,54 +713,60 @@ class SweepService:
             ins.pass_occupancy.observe(float(occ.mean()))
 
     def _burned_state(self, eng: PDESEngine, key: CompatKey, rows,
-                      n_pad: int, drows, tvec) -> SimState:
+                      n_pad: int, deltas: np.ndarray,
+                      trials: np.ndarray) -> SimState:
         """Assemble the post-burn-in state, reusing cached rows.
 
         Rows are independent rings, so cache-missing rows are burned in
         their own sub-pass and spliced next to cached rows — bit-identical
-        to burning the whole batch (tests/test_service.py).
+        to burning the whole batch (tests/test_service.py).  ``deltas`` and
+        ``trials`` are the pass's host columns, pad rows included.
         """
         import jax.numpy as jnp
         B = len(rows)
         if not key.burn:
             return eng.init(B + n_pad)
-        skey = key.stream_key
-        cached = [self.state_cache.get(skey + r) for r in rows]
-        missing = [i for i, c in enumerate(cached) if c is None]
-        self.stats.rows_from_state_cache += B - len(missing)
-        if missing:
-            ens = self._ens_extent(key)
-            m_pad = _round_up(len(missing), ens) - len(missing)
-            m_idx = np.asarray(missing, np.intp)
-            m_trials = np.concatenate(
-                [np.asarray(tvec)[m_idx],
-                 -1 - np.arange(m_pad, dtype=np.int32)])
-            m_deltas = np.concatenate(
-                [np.asarray(drows)[m_idx],
-                 np.full(m_pad, np.inf, np.float32)])
-            sub = eng.burn_in(eng.init(len(missing) + m_pad), key.seed,
-                              key.burn, deltas=jnp.asarray(m_deltas),
-                              trial_base=jnp.asarray(m_trials, jnp.int32))
-            self.stats.n_engine_calls += 1
-            self.stats.rows_burned += len(missing)
-            self.stats.engine_row_steps += (len(missing) + m_pad) * key.burn
-            self.state_cache.put_batch(
-                [skey + rows[i] for i in missing],
-                np.asarray(sub.tau)[:len(missing)],
-                np.asarray(sub.offset)[:len(missing)],
-                np.asarray(sub.offset_comp)[:len(missing)])
-            for j, i in enumerate(missing):
-                cached[i] = (np.asarray(sub.tau)[j],
-                             np.asarray(sub.offset)[j],
-                             np.asarray(sub.offset_comp)[j])
-        L = eng.cfg.L
-        tau = np.zeros((B + n_pad, L), np.float32)
-        off = np.zeros((B + n_pad,), np.float32)
-        comp = np.zeros((B + n_pad,), np.float32)
-        for i, (t, o, c) in enumerate(cached):
-            tau[i], off[i], comp[i] = t, o, c
-        return SimState(jnp.asarray(tau), jnp.asarray(off),
-                        jnp.asarray(comp), jnp.int32(key.burn))
+        with self._phase("pass.state"):
+            skey = key.stream_key
+            with self._phase("pass.state.lookup"):
+                cached = [self.state_cache.get(skey + r) for r in rows]
+            missing = [i for i, c in enumerate(cached) if c is None]
+            self.stats.rows_from_state_cache += B - len(missing)
+            if missing:
+                n = len(missing)
+                m_pad = _round_up(n, self._ens_extent(key)) - n
+                m_idx = np.asarray(missing, np.intp)
+                m_trials = np.concatenate(
+                    [trials[m_idx], -1 - np.arange(m_pad, dtype=np.int32)])
+                m_deltas = np.concatenate(
+                    [deltas[m_idx], np.full(m_pad, np.inf, np.float32)])
+                with self._phase("pass.burn"):
+                    sub = eng.burn_in(
+                        eng.init(n + m_pad), key.seed, key.burn,
+                        deltas=jnp.asarray(m_deltas),
+                        trial_base=jnp.asarray(m_trials, jnp.int32))
+                self.stats.n_engine_calls += 1
+                self.stats.rows_burned += n
+                self.stats.engine_row_steps += (n + m_pad) * key.burn
+                with self._phase("pass.state.fetch"):
+                    m_tau = np.asarray(sub.tau)[:n]
+                    m_off = np.asarray(sub.offset)[:n]
+                    m_comp = np.asarray(sub.offset_comp)[:n]
+                with self._phase("pass.state.put"):
+                    self.state_cache.put_batch(
+                        [skey + rows[i] for i in missing], m_tau, m_off,
+                        m_comp)
+                for j, i in enumerate(missing):
+                    cached[i] = (m_tau[j], m_off[j], m_comp[j])
+            with self._phase("pass.state.splice"):
+                tau = np.zeros((B + n_pad, eng.cfg.L), np.float32)
+                off = np.zeros((B + n_pad,), np.float32)
+                comp = np.zeros((B + n_pad,), np.float32)
+                for i, (t, o, c) in enumerate(cached):
+                    tau[i], off[i], comp[i] = t, o, c
+            with self._phase("pass.state.upload"):
+                return SimState(jnp.asarray(tau), jnp.asarray(off),
+                                jnp.asarray(comp), jnp.int32(key.burn))
 
     # -- per-request assembly ---------------------------------------------
 

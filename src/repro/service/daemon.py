@@ -141,8 +141,8 @@ def serve_daemon(cfg: DaemonConfig, *, service=None, log=None) -> "ServiceStats"
             "repro_daemon_rounds", "serve-loop rounds completed")
         phase_seconds = tel.registry.histogram(
             "repro_daemon_phase_seconds",
-            "daemon round phases: intake, flush, save "
-            "(schedule/engine live in repro_service_phase_seconds)",
+            "daemon round phases: intake, flush, save (the service's "
+            "own phases live in repro_service_phase_seconds)",
             unit="s")
 
     def save_metrics() -> None:
