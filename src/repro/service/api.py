@@ -28,6 +28,7 @@ import hashlib
 import json
 import math
 import time
+from collections.abc import Callable
 
 import numpy as np
 
@@ -154,6 +155,8 @@ class ServiceStats:
     rows_computed: int = 0        # union rows measured on-device
     rows_burned: int = 0          # rows burned on-device (state-cache misses)
     rows_from_state_cache: int = 0
+    rows_state_on_device: int = 0  # measured rows whose burned state never
+                                   # left the device (no row hit the cache)
     engine_row_steps: int = 0
     state_cache_hits: int = 0     # mirrors StateCache counters (hit/miss/
     state_cache_misses: int = 0   # eviction) so cache thrash under max_rows
@@ -250,6 +253,10 @@ class _ServiceInstruments:
             "rows_from_state_cache": c(
                 "repro_service_rows_from_state_cache",
                 "measurement rows whose burn-in was reused", unit="rows"),
+            "rows_state_on_device": c(
+                "repro_service_rows_state_on_device",
+                "measurement rows whose burned state reached the "
+                "measurement without a host round trip", unit="rows"),
             "engine_row_steps": c("repro_service_engine_row_steps",
                                   "rows x steps over every engine call "
                                   "(the honest compute unit)",
@@ -653,14 +660,22 @@ class SweepService:
                 requesters=sorted({j.requester for j in p.jobs}))) as sp:
             pre_cached = self.stats.rows_from_state_cache
             pre_burned = self.stats.rows_burned
-            state = self._burned_state(eng, key, p.rows, n_pad, deltas,
-                                       trials)
-            with self._phase("pass.measure"):
-                _, stats = eng.run(state, key.seed, key.n_steps,
-                                   deltas=drows, trial_base=tvec)
+            state, fill, on_device = self._burned_state(
+                eng, key, p.rows, n_pad, deltas, trials)
+            try:
+                with self._phase("pass.measure"):
+                    _, stats = eng.run(state, key.seed, key.n_steps,
+                                       deltas=drows, trial_base=tvec)
+            finally:
+                # the cache fill runs while the device measures, and also
+                # when the measurement raises: a retry finds the rows
+                if fill is not None:
+                    fill()
             self.stats.n_passes += 1
             self.stats.n_engine_calls += 1
             self.stats.rows_computed += B
+            if on_device:
+                self.stats.rows_state_on_device += B
             self.stats.engine_row_steps += (B + n_pad) * key.n_steps
 
             with self._phase("pass.stats.fetch"):
@@ -713,25 +728,34 @@ class SweepService:
             ins.pass_occupancy.observe(float(occ.mean()))
 
     def _burned_state(self, eng: PDESEngine, key: CompatKey, rows,
-                      n_pad: int, deltas: np.ndarray,
-                      trials: np.ndarray) -> SimState:
-        """Assemble the post-burn-in state, reusing cached rows.
+                      n_pad: int, deltas: np.ndarray, trials: np.ndarray
+                      ) -> tuple[SimState, Callable[[], None] | None, bool]:
+        """The post-burn-in state, reusing cached rows.
 
         Rows are independent rings, so cache-missing rows are burned in
         their own sub-pass and spliced next to cached rows — bit-identical
         to burning the whole batch (tests/test_service.py).  ``deltas`` and
         ``trials`` are the pass's host columns, pad rows included.
+
+        Returns ``(state, fill, on_device)``.  ``fill`` (None when nothing
+        was burned) puts the burned rows into the state cache; the caller
+        runs it once the measurement is enqueued, so the host's copies
+        overlap the device's work.  ``on_device`` is true when no row hit
+        the cache: ``state`` then holds the burn's output as it stands,
+        which never leaves the device on its way to the measurement.
         """
         import jax.numpy as jnp
         B = len(rows)
         if not key.burn:
-            return eng.init(B + n_pad)
+            return eng.init(B + n_pad), None, False
         with self._phase("pass.state"):
             skey = key.stream_key
             with self._phase("pass.state.lookup"):
                 cached = [self.state_cache.get(skey + r) for r in rows]
             missing = [i for i, c in enumerate(cached) if c is None]
             self.stats.rows_from_state_cache += B - len(missing)
+            on_device = len(missing) == B
+            fill = None
             if missing:
                 n = len(missing)
                 m_pad = _round_up(n, self._ens_extent(key)) - n
@@ -748,25 +772,49 @@ class SweepService:
                 self.stats.n_engine_calls += 1
                 self.stats.rows_burned += n
                 self.stats.engine_row_steps += (n + m_pad) * key.burn
-                with self._phase("pass.state.fetch"):
-                    m_tau = np.asarray(sub.tau)[:n]
-                    m_off = np.asarray(sub.offset)[:n]
-                    m_comp = np.asarray(sub.offset_comp)[:n]
-                with self._phase("pass.state.put"):
-                    self.state_cache.put_batch(
-                        [skey + rows[i] for i in missing], m_tau, m_off,
-                        m_comp)
-                for j, i in enumerate(missing):
-                    cached[i] = (m_tau[j], m_off[j], m_comp[j])
+                keys = [skey + rows[i] for i in missing]
+                burned = (sub.tau, sub.offset, sub.offset_comp)
+                if on_device:
+                    host = None
+                    for a in burned:
+                        a.copy_to_host_async()
+                else:
+                    host = self._fetch_rows(burned, n)
+                    for j, i in enumerate(missing):
+                        cached[i] = tuple(a[j] for a in host)
+
+                def fill():
+                    with self._phase("pass.state"):
+                        got = host or self._fetch_rows(burned, n)
+                        with self._phase("pass.state.put"):
+                            self.state_cache.put_batch(keys, *got)
             with self._phase("pass.state.splice"):
-                tau = np.zeros((B + n_pad, eng.cfg.L), np.float32)
-                off = np.zeros((B + n_pad,), np.float32)
-                comp = np.zeros((B + n_pad,), np.float32)
-                for i, (t, o, c) in enumerate(cached):
-                    tau[i], off[i], comp[i] = t, o, c
+                if on_device:
+                    # The burn ran the pass's own rows in order (m_pad ==
+                    # n_pad, the same trial and Δ columns), so its output
+                    # is the measurement's input as it stands, sharded on
+                    # the mesh where the backend is.  Its pad rows start
+                    # the measurement burned where a host splice leaves
+                    # them zero: independent rings on out-of-band stream
+                    # indices, sliced off before any reduction.
+                    parts = burned
+                else:
+                    parts = (np.zeros((B + n_pad, eng.cfg.L), np.float32),
+                             np.zeros((B + n_pad,), np.float32),
+                             np.zeros((B + n_pad,), np.float32))
+                    tau, off, comp = parts
+                    for i, (t, o, c) in enumerate(cached):
+                        tau[i], off[i], comp[i] = t, o, c
             with self._phase("pass.state.upload"):
-                return SimState(jnp.asarray(tau), jnp.asarray(off),
-                                jnp.asarray(comp), jnp.int32(key.burn))
+                # device arrays pass through jnp.asarray as they are
+                state = SimState(*(jnp.asarray(a) for a in parts),
+                                 jnp.int32(key.burn))
+        return state, fill, on_device
+
+    def _fetch_rows(self, arrays, n: int) -> list[np.ndarray]:
+        """The first ``n`` rows of burned device arrays, on the host."""
+        with self._phase("pass.state.fetch"):
+            return [np.asarray(a)[:n] for a in arrays]
 
     # -- per-request assembly ---------------------------------------------
 

@@ -446,19 +446,21 @@ SERVICE_SPANS = {
 
 
 def _serve_streaming(telemetry=None):
-    """One request served through a streaming sink, so ``service.flush``
-    runs; returns its result."""
+    """Two requests served through a streaming sink, so ``service.flush``
+    runs: a fresh one, whose burned state goes straight to the measurement,
+    then a follow-up that partly hits the state cache, so the host splice
+    and upload run too.  Returns both responses' records."""
     from repro.experiments import WindowSweep
     from repro.service import SweepService
     svc = SweepService(telemetry=telemetry)
     got = []
     svc.on_response = got.append
-    svc.submit(WindowSweep(deltas=(2.0, 4.0, math.inf), **COMMON),
-               requester="alice")
-    svc.drain()
-    (resp,) = got
-    assert resp.error is None
-    return resp.result
+    for deltas in ((2.0, 4.0, math.inf), (2.0, 8.0)):
+        svc.submit(WindowSweep(deltas=deltas, **COMMON), requester="alice")
+        svc.drain()
+    assert [r.error for r in got] == [None, None]
+    assert 0 < svc.stats.rows_from_state_cache < svc.stats.rows_computed
+    return [r.result.records for r in got]
 
 
 def test_profiler_sink_puts_service_spans_on_the_host_plane(tmp_path):
@@ -474,7 +476,7 @@ def test_profiler_sink_puts_service_spans_on_the_host_plane(tmp_path):
             jax.profiler.stop_trace()
     finally:
         set_tracer(prev)
-    assert traced.records == baseline.records
+    assert traced == baseline
     (path,) = (tmp_path / "plugins" / "profile").glob("*/*.xplane.pb")
     data = ProfileData.from_serialized_xspace(path.read_bytes())
     spans: dict[str, list] = {}
@@ -508,7 +510,7 @@ def test_service_responses_are_bit_identical_under_either_sink():
             traced = _serve_streaming()
         finally:
             set_tracer(prev)
-        assert traced.records == baseline.records, type(sink).__name__
+        assert traced == baseline, type(sink).__name__
     assert {e["name"] for e in chrome.events} == set(SERVICE_SPANS)
     assert check_trace(chrome.to_dict()) == []
 

@@ -241,6 +241,134 @@ def test_partial_state_cache_overlap_bit_identical():
 
 
 # ---------------------------------------------------------------------------
+# burned state kept on the device: no row hit the cache
+# ---------------------------------------------------------------------------
+
+
+def _rec_eq(a, b):
+    """Float-equal records; the sharded backend's ``wa`` is NaN by contract
+    and NaN != NaN under dataclass equality."""
+    da, db = dataclasses.asdict(a), dataclasses.asdict(b)
+    return all(v == db[k] or (isinstance(v, float) and math.isnan(v)
+                              and math.isnan(db[k]))
+               for k, v in da.items())
+
+
+def state_on_device_checks(mesh=None) -> dict:
+    """Serve a pass no row of which is cached, then one that partly hits
+    the cache; every entry of the returned dict should be True.
+
+    On a mesh the first pass has a pad row (9 rows over an ensemble extent
+    of 2), which now starts the measurement burned instead of zeroed."""
+    from repro.core import PDESConfig, PDESEngine
+    backend = "pallas_multistep" if mesh is None else "sharded"
+    common = dict(COMMON, replicas=3, backend=backend)
+    fresh = WindowSweep(deltas=(2.0, 4.0, math.inf), **common)
+    follow = WindowSweep(deltas=(2.0, 8.0), **common)   # 2.0 rows cached
+    svc = SweepService(mesh=mesh)
+    svc.submit(fresh, requester="alice")
+    (r1,) = svc.drain()
+    n = fresh.n_trajectories
+    out = {
+        "fresh_bit_identical": all(_rec_eq(x, y) for x, y in zip(
+            r1.result.records,
+            run_window_sweep(fresh, mesh=mesh).records)),
+        "fresh_on_device": (svc.stats.rows_state_on_device
+                            == svc.stats.rows_computed == n),
+    }
+
+    # the cache holds what a direct burn-in of those rows gives
+    eng = PDESEngine(PDESConfig(L=16, n_v=2, delta=math.inf),
+                     backend=backend, k_fuse=COMMON["k_fuse"], mesh=mesh)
+    rows = [(w * fresh.replicas + r, d) for w, d in enumerate(fresh.deltas)
+            for r in range(fresh.replicas)]
+    pad = 0 if mesh is None else -n % mesh.shape["data"]
+    trials = [t for t, _ in rows] + [-1 - i for i in range(pad)]
+    deltas = [d for _, d in rows] + [math.inf] * pad
+    burned = eng.burn_in(eng.init(n + pad), fresh.seed, fresh.burn_in,
+                         deltas=jax.numpy.asarray(deltas, np.float32),
+                         trial_base=jax.numpy.asarray(trials, np.int32))
+    skey = CompatKey(L=16, n_v=2, backend=backend, window="exact",
+                     k_fuse=COMMON["k_fuse"], rd_mode=False,
+                     border_both=False, seed=fresh.seed,
+                     burn=fresh.burn_in, n_steps=fresh.n_steps).stream_key
+    host = [np.asarray(a) for a in
+            (burned.tau, burned.offset, burned.offset_comp)]
+    out["cache_is_direct_burn"] = len(svc.state_cache) == n and all(
+        all(np.array_equal(c, a[i]) for c, a in
+            zip(svc.state_cache.get(skey + row), host))
+        for i, row in enumerate(rows))
+
+    on_device = svc.stats.rows_state_on_device
+    svc.submit(follow, requester="alice")
+    (r2,) = svc.drain()
+    out["partial_bit_identical"] = all(_rec_eq(x, y) for x, y in zip(
+        r2.result.records, run_window_sweep(follow, mesh=mesh).records))
+    out["partial_through_host"] = (
+        0 < svc.stats.rows_from_state_cache < follow.n_trajectories
+        and svc.stats.rows_state_on_device == on_device)
+    return out
+
+
+STATE_ON_DEVICE_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import json, sys
+sys.path.insert(0, "tests")
+from repro.compat import make_mesh
+from test_service import state_on_device_checks
+print(json.dumps(state_on_device_checks(make_mesh((2, 4), ("data", "model")))))
+"""
+
+
+@pytest.mark.parametrize("where", [
+    "one_device", pytest.param("sharded", marks=pytest.mark.distributed)])
+def test_all_miss_pass_keeps_the_burned_state_on_device(where):
+    """A pass no row of which hit the cache measures the burn's output as
+    it stands: bit-identical to a direct run, counted in
+    ``rows_state_on_device``, with the cache filled behind the measurement
+    exactly as a direct burn-in gives; a partial hit keeps the splice."""
+    if where == "one_device":
+        results = state_on_device_checks()
+    else:
+        env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+        env.pop("XLA_FLAGS", None)
+        out = subprocess.run([sys.executable, "-c", STATE_ON_DEVICE_SCRIPT],
+                             capture_output=True, text=True, env=env,
+                             cwd=REPO)
+        assert out.returncode == 0, out.stderr[-4000:]
+        results = json.loads(out.stdout.strip().splitlines()[-1])
+    assert results == {k: True for k in results}, results
+
+
+def test_failed_measurement_still_fills_the_state_cache(monkeypatch):
+    """The cache fill runs behind the measurement, and also when the
+    measurement raises: the retry finds every burned row in the cache."""
+    from repro.core.engine import PDESEngine
+    real_run = PDESEngine.run
+    calls = []
+
+    def flaky_run(self, *args, **kwargs):
+        calls.append(None)
+        if len(calls) == 1:
+            raise RuntimeError("measurement fault")
+        return real_run(self, *args, **kwargs)
+
+    monkeypatch.setattr(PDESEngine, "run", flaky_run)
+    spec = WindowSweep(deltas=(2.0, 4.0), **COMMON)
+    n = spec.n_trajectories
+    svc = SweepService(engine_retries=1, retry_base_s=0.0)
+    svc.submit(spec, requester="alice")
+    (resp,) = svc.drain()
+    assert resp.error is None and svc.stats.n_retries == 1
+    assert svc.stats.rows_burned == n            # burned before the fault
+    assert svc.stats.state_cache_hits == n       # the retry hit every row
+    assert svc.stats.rows_from_state_cache == n
+    assert svc.stats.rows_state_on_device == 0   # ...through the host splice
+    assert resp.result.records == run_window_sweep(spec).records
+
+
+# ---------------------------------------------------------------------------
 # sharded gate: coalesced mesh pass == direct sharded sweep (subprocess)
 # ---------------------------------------------------------------------------
 
