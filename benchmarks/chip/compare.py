@@ -1,13 +1,25 @@
 """The comparison that decides ``correct``: served records against the reference.
 
 A sample of the answered requests, drawn from the run's seed and always
-holding the one that asked for the most PE-steps, is recomputed by the plain
-reference (``reference.py``) from its spec alone.  The number compared is
-``records_rel_gap``: the widest relative gap, over the sampled requests,
+holding the one that asked for the most PE-steps, is recomputed by the
+configuration's plain reference from its spec alone.  The number compared
+is ``records_rel_gap``: the widest relative gap, over the sampled requests,
 their per-Δ records and the configuration's ``compare_fields``, between what
 the requester received and what the reference computes.  A record that is
 missing, has another Δ, or is not finite where the reference is, reads as an
-infinite gap.  Where the check names ``limit`` it comes from the cell file.
+infinite gap.  A list-valued field (a time series) compares element by
+element; another length, or a list where the reference has a scalar, reads
+as infinite.  Where the check names ``limit`` it comes from the cell file.
+
+The reference is the module the configuration names under ``reference``
+(default ``reference``, i.e. ``reference.py``), found by
+``harness.Layout.reference``.  It imports nothing of the program and
+declares what it implements: ``SWEEP_FIELDS``, the ``sweep_fields`` keys it
+reads, and ``WINDOWS``, the ``window`` values.  Its
+``sweep_records(spec, *, pad_rows, dtype)`` gets ``L``, ``n_v``,
+``deltas``, ``replicas``, ``burn_in``, ``n_steps``, ``k_fuse``, ``seed``,
+``steady_frac``, ``window`` and every ``sweep_fields`` key of the
+configuration, and returns one record (a dict of fields) per Δ.
 """
 from __future__ import annotations
 
@@ -16,10 +28,18 @@ import sys
 
 import numpy as np
 
-from . import reference, traffic
+from . import traffic
+
+_SEQ = (list, tuple)
 
 
-def rel_gap(got: float, want: float) -> float:
+def rel_gap(got, want) -> float:
+    """Relative gap of two numbers, or the widest of two lists' elements."""
+    if isinstance(got, _SEQ) or isinstance(want, _SEQ):
+        if not (isinstance(got, _SEQ) and isinstance(want, _SEQ)
+                and len(got) == len(want)):
+            return math.inf
+        return max(map(rel_gap, got, want), default=0.0)
     if not math.isfinite(got):
         return 0.0 if got == want else math.inf
     return abs(got - want) / abs(want) if want else abs(got - want)
@@ -55,14 +75,17 @@ def sample(served, n: int, seed: int) -> list:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def reference_records(spec: dict, pad_rows: int, dtype=None) -> list[dict]:
+def reference_records(ref, config: dict, spec: dict, pad_rows: int,
+                      dtype=None) -> list[dict]:
+    """The records reference module ``ref`` computes for request ``spec``."""
     import jax.numpy as jnp
     ref_spec = dict(L=spec["Ls"][0], n_v=spec["n_vs"][0],
                     deltas=spec["deltas"], replicas=spec["replicas"],
                     burn_in=spec["burn_in"], n_steps=spec["n_steps"],
                     k_fuse=spec["k_fuse"], seed=spec["seed"],
-                    steady_frac=spec["steady_frac"])
-    recs = reference.sweep_records(
+                    steady_frac=spec["steady_frac"], window=spec["window"])
+    ref_spec.update(config.get("sweep_fields", {}))
+    recs = ref.sweep_records(
         ref_spec, pad_rows=pad_rows,
         dtype=jnp.float32 if dtype is None else dtype)
     return [dict(r, delta=float(d)) for r, d in zip(recs, spec["deltas"])]
@@ -73,14 +96,14 @@ def pad_rows(specs) -> int:
     return max(len(s["deltas"]) * s["replicas"] for s in specs)
 
 
-def check(served, config: dict, check_spec: dict, seed: int) -> dict:
+def check(served, config: dict, check_spec: dict, seed: int, ref) -> dict:
     """``{"records_rel_gap": {"value": ..., "limit": ...}}`` for one run."""
     fields = config["compare_fields"]
     worst = dict.fromkeys(fields, 0.0 if served else math.inf)
     if served:
         pad = pad_rows(s.req.spec for s in served)
     for s in sample(served, int(check_spec["requests"]), seed):
-        want = reference_records(s.req.spec, pad)
+        want = reference_records(ref, config, s.req.spec, pad)
         got = [vars(r) for r in s.response.result.records]
         for f, g in field_gaps(got, want, fields).items():
             worst[f] = max(worst[f], g)

@@ -2,10 +2,11 @@
 """The control of ``correct``: the reference in bfloat16 in the program's place.
 
 For each seed, the first requests a cell's traffic sends (as many as the
-cell's check samples) are computed by the plain reference twice, in the
-configuration's float32 and in bfloat16, the nearest precision below, and
-the bfloat16 records are compared with the float32 ones exactly as a run
-compares the served records.  The smallest gap over the seeds is the upper
+cell's check samples) are computed by the configuration's own plain
+reference (its ``reference`` module) twice, in the configuration's
+float32 and in bfloat16, the nearest precision below, and the bfloat16
+records are compared with the float32 ones exactly as a run compares the
+served records.  The smallest gap over the seeds is the upper
 reading the cell's limit must stay below.
 
 Usage, on the chip, from the root of a checkout::
@@ -47,11 +48,12 @@ def control_gap(cell: str, seed: int, layout) -> float:
     import jax.numpy as jnp
     from benchmarks.chip import compare
     specs, config = first_requests(cell, seed, layout)
+    ref = layout.reference(config)
     pad = compare.pad_rows(specs)
     worst = 0.0
     for spec in specs:
-        want = compare.reference_records(spec, pad)
-        got = compare.reference_records(spec, pad, jnp.bfloat16)
+        want = compare.reference_records(ref, config, spec, pad)
+        got = compare.reference_records(ref, config, spec, pad, jnp.bfloat16)
         worst = max(worst, compare.records_gap(got, want,
                                                config["compare_fields"]))
     return worst

@@ -4,11 +4,20 @@ Everything a cell is made of is data, found by name:
 
 * ``workloads/<cell>.json``: the cell's ``config``, ``traffic``, ``chips``
   and its output ``check`` (requests sampled, limits);
-* ``configs/<config>.json``: the deployment (ring, Δ grid, backend, mesh);
+* ``configs/<config>.json``: the deployment (ring, Δ grid, backend, mesh),
+  with two optional keys: ``sweep_fields``, further ``WindowSweep`` fields
+  that every request carries (``traffic.spec_for``), and ``reference``, the
+  name of its plain-reference module (default ``reference``);
+* ``<reference>.py``: a plain reference, which declares the
+  ``sweep_fields`` keys (``SWEEP_FIELDS``) and ``window`` values
+  (``WINDOWS``) it implements; the contract is in ``compare.py``;
 * ``traffic/<mix>.json``: the mix's parameters, read by ``traffic.py``;
 * ``metrics/<metric>.py``: one reader per metric, ``read(run)``, which
   returns a number or None where it finds nothing to read;
 * ``BENCHMARK.json`` at the root: which metrics each cell reports.
+
+A configuration whose reference does not declare its ``window`` or one of
+its ``sweep_fields`` is refused before anything is served.
 
 A run: check the platform, build the service, serve the mix's warm-up
 requests (every pass shape the mix can produce), then serve the mix for
@@ -16,7 +25,7 @@ requests (every pass shape the mix can produce), then serve the mix for
 ``step(force=False)``), with every request and response through the wire
 codec.  Requests due in the window are drained after it.  Then read the
 device's peak memory, free the service, and compare a sample of the
-answered requests with the plain reference (``reference.py``).
+answered requests with the configuration's plain reference.
 """
 from __future__ import annotations
 
@@ -33,16 +42,13 @@ import tempfile
 import time
 
 from . import compare, trace_reduce, traffic
+from .traffic import BenchError
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 #: the program's compile cache, at a fixed path inside the checkout
 CACHE_DIR = ROOT / ".jax_cache"
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-
-
-class BenchError(Exception):
-    """The run cannot be made here; no result is printed."""
 
 
 def log(msg: str) -> None:
@@ -66,17 +72,41 @@ class Layout:
         path = self.data / "metrics" / f"{metric}.py"
         if not path.is_file():
             raise BenchError(f"no reader for metric {metric!r} ({path})")
-        spec = importlib.util.spec_from_file_location(
-            "chipbench_metric_" + metric.replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load(path, "chipbench_metric_" + metric.replace(".", "_")).read
+
+    def reference(self, config: dict):
+        """The plain-reference module ``config`` names, checked against it."""
+        name = config.get("reference", "reference")
+        path = self.data / f"{name}.py"
+        if not name.isidentifier() or not path.is_file():
+            raise BenchError(f"no reference module named {name!r} ({path})")
+        ref = _load(path, "chipbench_reference_" + name)
+        windows = getattr(ref, "WINDOWS", ())
+        fields = getattr(ref, "SWEEP_FIELDS", ())
+        of = f"of config {config.get('name')!r}"
+        if config["window"] not in windows:
+            raise BenchError(
+                f"reference {name!r} implements window {windows}, not "
+                f"window {config['window']!r} {of}")
+        for key in config.get("sweep_fields", {}):
+            if key not in fields:
+                raise BenchError(
+                    f"reference {name!r} does not implement sweep_fields "
+                    f"key {key!r} {of} (it declares {fields})")
+        return ref
 
     def metrics(self, cell: str, traced: bool) -> list[dict]:
         """The cell's end-to-end metrics, or with ``traced`` its per-layer."""
         bench = json.loads(self.benchmark.read_text())
         group = bench["per_layer" if traced else "end_to_end"]
         return [m for m in group if cell in m.get("workloads", [cell])]
+
+
+def _load(path: pathlib.Path, module: str):
+    spec = importlib.util.spec_from_file_location(module, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @dataclasses.dataclass
@@ -249,6 +279,8 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     chips = int(spec["chips"])
     readers = [(m, layout.reader(m["name"]))
                for m in layout.metrics(cell, trace)]
+    ref = layout.reference(config)
+    traffic.spec_for(config, mix["request"], 0)   # refuses clashing fields
     import jax
     if require_tpu:
         devs = check_platform(chips)
@@ -267,7 +299,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
     failed = sum(1 for s in run.served if not s.ok)
     log(f"window {seconds:g} s: {len(run.served)} requests, {failed} failed, "
         f"last answer at {run.window_end:.3f} s, stats {run.stats}")
-    checks = compare.check(run.served, config, spec["check"], seed)
+    checks = compare.check(run.served, config, spec["check"], seed, ref)
     correct = failed == 0 and all(c["value"] <= c["limit"]
                                   for c in checks.values())
     out = {"correct": correct, "attempted": len(run.served),

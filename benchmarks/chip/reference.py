@@ -45,6 +45,11 @@ _PE_C = np.uint32(0xD3A2646C)
 _WORD0_C = np.uint32(0x68E31DA4)
 _WORD1_C = np.uint32(0xB5297A4D)
 
+#: what this reference implements (the contract in ``compare.py``): the
+#: exact window, and no configuration ``sweep_fields``
+WINDOWS = ("exact",)
+SWEEP_FIELDS = ()
+
 FIELDS = ("u", "u_err", "w2", "w2_err", "w", "wa", "spread", "rate",
           "rate_err")
 

@@ -1,7 +1,11 @@
 """The one traffic generator: turns a mix's parameters into sweep requests.
 
 A mix is a JSON file under ``traffic/``; the generator reads it with the
-cell's configuration and the run's ``--seed`` and nothing else.  Two loops:
+cell's configuration and the run's ``--seed`` and nothing else.  Every
+request carries the fields ``spec_for`` builds from the configuration and
+the mix, then the configuration's optional ``sweep_fields``: further
+``WindowSweep`` fields (a payload's parameters, ``rd_mode``) that go into
+every request, warm-up requests too.  Two loops:
 
 * ``closed``: ``clients`` requesters; each sends its next request (a copy of
   the mix's ``request``, with a fresh stream seed) when the previous one is
@@ -29,6 +33,10 @@ import numpy as np
 SEED_RANGE = (1, 2**31 - 1)
 
 
+class BenchError(Exception):
+    """The run cannot be made here; no result is printed."""
+
+
 @dataclasses.dataclass
 class Request:
     """One request of a run: when it is due, who sends it, what it asks."""
@@ -41,8 +49,12 @@ class Request:
 
 
 def spec_for(config: dict, request: dict, seed: int) -> dict:
-    """WindowSweep fields of one request under ``config``."""
-    return dict(
+    """WindowSweep fields of one request under ``config``.
+
+    The configuration's ``sweep_fields`` come last; one that would set a
+    field built here is refused.  ``WindowSweep`` refuses unknown ones.
+    """
+    spec = dict(
         Ls=(int(config["L"]),), n_vs=(int(config["n_v"]),),
         deltas=tuple(math.inf if d == "inf" else float(d)
                      for d in config["deltas"]),
@@ -50,6 +62,14 @@ def spec_for(config: dict, request: dict, seed: int) -> dict:
         burn_in=int(request["burn_in"]), backend=config["backend"],
         window=config["window"], k_fuse=int(config["k_fuse"]),
         steady_frac=float(request.get("steady_frac", 0.5)), seed=int(seed))
+    extra = config.get("sweep_fields", {})
+    clash = sorted(set(extra) & set(spec))
+    if clash:
+        raise BenchError(f"sweep_fields {clash} of config "
+                         f"{config.get('name')!r} would set fields the "
+                         f"harness builds itself ({sorted(spec)})")
+    spec.update(extra)
+    return spec
 
 
 def pe_steps(spec: dict) -> int:

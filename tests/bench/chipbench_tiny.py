@@ -2,9 +2,9 @@
 
 ``tiny_layout(tmp)`` writes configs, mixes and cells that mirror the real
 ones at a size the Pallas interpreter runs in seconds (rings of 64 PEs,
-4 replicas per Δ, 32 + 32 steps), copies the real metric readers, and a
-``BENCHMARK.json`` whose cells are the tiny ones.  Nothing here touches
-the real data files.
+4 replicas per Δ, 32 + 32 steps), copies the real metric readers and the
+plain reference, and writes a ``BENCHMARK.json`` whose cells are the tiny
+ones.  Nothing here touches the real data files.
 
 The open-loop sessions mix (``TENANTS_MIX``) and its metrics have no cell
 in ``BENCHMARK.json`` yet: its rate has to come from a knee sweep on the
@@ -66,6 +66,59 @@ TENANTS_PER_LAYER = [
     _metric("engine.compiles", "count", "lower", "request_p95_s", "engine")]
 
 
+#: the line of ``reference.py`` that applies the causality rule Eq. (1)
+EQ1 = "eq1 = (~picks_left | (tau <= left)) & (~picks_right | (tau <= right))"
+#: requests that do not set ``rd_mode`` go to the plain reference as it is
+RD_DISPATCH = """
+
+_rd_records = sweep_records
+
+
+def sweep_records(spec, **kw):
+    if spec["rd_mode"]:
+        return _rd_records(spec, **kw)
+    from benchmarks.chip import reference
+    return reference.sweep_records(spec, **kw)
+"""
+
+
+def rd_reference_source() -> str:
+    """A plain reference that implements ``sweep_fields {"rd_mode": ...}``.
+
+    Random deposition drops the causality rule: ``reference.py`` with Eq. (1)
+    replaced by "always", declaring ``rd_mode``.
+    """
+    src = (DATA / "reference.py").read_text()
+    assert src.count(EQ1) == 1 and src.count("SWEEP_FIELDS = ()") == 1
+    return (src.replace(EQ1, "eq1 = jnp.ones(tau.shape, bool)")
+            .replace("SWEEP_FIELDS = ()", 'SWEEP_FIELDS = ("rd_mode",)')
+            + RD_DISPATCH)
+
+
+def add_cell(layout, cell: str, config: dict, modules=None) -> None:
+    """Add ``cell``, serving ``tiny_study`` on a new ``config``, to a layout.
+
+    ``modules`` maps module names to the source of further ``.py`` files
+    (a plain reference) written beside the data files.
+    """
+    data = layout.data
+    (data / "configs" / f"{config['name']}.json").write_text(
+        json.dumps(config))
+    for name, src in (modules or {}).items():
+        (data / f"{name}.py").write_text(src)
+    (data / "workloads" / f"{cell}.json").write_text(json.dumps(
+        {"config": config["name"], "traffic": "tiny_study", "chips": 1,
+         "check": {"requests": 3, "limit": 1e-3}}))
+    bench = json.loads(layout.benchmark.read_text())
+    bench["workloads"].append({"name": cell, "config": config["name"],
+                               "traffic": "tiny_study", "chips": 1,
+                               "why": "added by a test"})
+    for m in bench["end_to_end"]:
+        if m["name"] == "pe_steps_per_s":
+            m["workloads"].append(cell)
+    layout.benchmark.write_text(json.dumps(bench))
+
+
 def _read(kind: str, name: str) -> dict:
     return json.loads((DATA / kind / f"{name}.json").read_text())
 
@@ -73,6 +126,7 @@ def _read(kind: str, name: str) -> dict:
 def tiny_layout(tmp: pathlib.Path, limit: float = 1e-3) -> harness.Layout:
     tmp = pathlib.Path(tmp)
     shutil.copytree(DATA / "metrics", tmp / "metrics")
+    shutil.copy(DATA / "reference.py", tmp)
     for kind in ("configs", "traffic", "workloads"):
         (tmp / kind).mkdir()
     cfg = _read("configs", "dstudy_L10k_nv10")

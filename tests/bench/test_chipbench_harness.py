@@ -9,7 +9,7 @@ import sys
 
 import pytest
 
-from chipbench_tiny import ROOT, run_tiny, tiny_layout
+from chipbench_tiny import ROOT, rd_reference_source, run_tiny, tiny_layout
 from benchmarks.chip import harness
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -110,32 +110,41 @@ def test_benchmark_json_keeps_the_contract_shape():
 
 
 def test_a_cell_added_as_data_files_alone_runs(tmp_path):
-    """A new config, mix and cell, plus their BENCHMARK.json entry: no code."""
+    """A new config, mix and cell, plus their BENCHMARK.json entry: no code.
+
+    A second config also brings request fields (``sweep_fields``) and names
+    its own plain reference, one more ``.py`` file beside the data files.
+    """
     layout = tiny_layout(tmp_path)
     cfg = json.loads((tmp_path / "configs" / "tiny.json").read_text())
     cfg.update(L=48, n_v=3, deltas=[2, "inf"])
+    own = dict(cfg, sweep_fields={"rd_mode": True}, reference="rd_ref")
     (tmp_path / "configs" / "added.json").write_text(json.dumps(cfg))
+    (tmp_path / "configs" / "added_own.json").write_text(json.dumps(own))
+    (tmp_path / "rd_ref.py").write_text(rd_reference_source())
     (tmp_path / "traffic" / "added_mix.json").write_text(json.dumps(
         {"loop": "closed", "clients": 2,
          "request": {"replicas": 3, "burn_in": 16, "n_steps": 48}}))
-    (tmp_path / "workloads" / "added.cell.json").write_text(json.dumps(
-        {"config": "added", "traffic": "added_mix", "chips": 1,
-         "check": {"requests": 2, "limit": 1e-3}}))
     bench = json.loads(layout.benchmark.read_text())
-    bench["workloads"].append({"name": "added.cell", "config": "added",
-                               "traffic": "added_mix", "chips": 1,
-                               "why": "added from data files"})
-    for m in bench["end_to_end"]:
-        if m["name"] == "pe_steps_per_s":
-            m["workloads"].append("added.cell")
+    for cell, config in (("added.cell", "added"), ("added.own", "added_own")):
+        (tmp_path / "workloads" / f"{cell}.json").write_text(json.dumps(
+            {"config": config, "traffic": "added_mix", "chips": 1,
+             "check": {"requests": 2, "limit": 1e-3}}))
+        bench["workloads"].append({"name": cell, "config": config,
+                                   "traffic": "added_mix", "chips": 1,
+                                   "why": "added from data files"})
+        for m in bench["end_to_end"]:
+            if m["name"] == "pe_steps_per_s":
+                m["workloads"].append(cell)
     layout.benchmark.write_text(json.dumps(bench))
-    out = run_tiny(layout, "added.cell", seconds=1.5)
-    assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] >= 2
-    assert set(out["metrics"]) == {"pe_steps_per_s", "setup_s"}
-    assert out["metrics"]["pe_steps_per_s"]["unit"] == "pe-steps/s"
-    assert list(out)[-1] == "checks"
-    assert out["device"]["count"] >= 1
+    for cell in ("added.cell", "added.own"):
+        out = run_tiny(layout, cell, seconds=1.5)
+        assert out["correct"] is True and out["failed"] == 0
+        assert out["attempted"] >= 2
+        assert set(out["metrics"]) == {"pe_steps_per_s", "setup_s"}
+        assert out["metrics"]["pe_steps_per_s"]["unit"] == "pe-steps/s"
+        assert list(out)[-1] == "checks"
+        assert out["device"]["count"] >= 1
 
 
 def test_tenants_cell_reports_latency_and_runs_traced(tmp_path):

@@ -1,5 +1,7 @@
 """The chip benchmark's traffic generator: seeded, fixed work, bounded shapes."""
 import collections
+import json
+import math
 
 import numpy as np
 import pytest
@@ -85,6 +87,79 @@ def test_closed_loop_stops_issuing_after_the_window():
     (r,) = src.take_due(0.0)
     src.answered(r, 2.5)
     assert src.take_due(10.0) == [] and src.next_due() is None
+
+
+#: (config, mix, the request ``spec_for`` builds for stream seed 12345,
+#: and its wire line) as the two cells send them
+GOLDEN = [
+    ("dstudy_L10k_nv10", "study",
+     {"Ls": (10000,), "n_vs": (10,),
+      "deltas": (1.0, 5.0, 10.0, 100.0, math.inf),
+      "replicas": 256, "n_steps": 1024, "burn_in": 1024,
+      "backend": "pallas_multistep", "window": "exact", "k_fuse": 16,
+      "steady_frac": 0.5, "seed": 12345},
+     '{"version": 2, "requester": "client0", "spec": {"Ls": [10000], '
+     '"n_vs": [10], "deltas": [1.0, 5.0, 10.0, 100.0, "inf"], '
+     '"replicas": 256, "n_steps": 1024, "burn_in": 1024, '
+     '"backend": "pallas_multistep", "window": "exact", "k_fuse": 16, '
+     '"rd_mode": false, "border_both": false, "steady_frac": 0.5, '
+     '"seed": 12345}}'),
+    ("ring4_L262k_nv10", "ring_study",
+     {"Ls": (262144,), "n_vs": (10,),
+      "deltas": (1.0, 5.0, 10.0, 100.0, math.inf),
+      "replicas": 32, "n_steps": 512, "burn_in": 512, "backend": "sharded",
+      "window": "exact", "k_fuse": 16, "steady_frac": 0.5, "seed": 12345},
+     '{"version": 2, "requester": "client0", "spec": {"Ls": [262144], '
+     '"n_vs": [10], "deltas": [1.0, 5.0, 10.0, 100.0, "inf"], '
+     '"replicas": 32, "n_steps": 512, "burn_in": 512, '
+     '"backend": "sharded", "window": "exact", "k_fuse": 16, '
+     '"rd_mode": false, "border_both": false, "steady_frac": 0.5, '
+     '"seed": 12345}}'),
+]
+
+
+@pytest.mark.parametrize("config, mix, spec, line", GOLDEN,
+                         ids=["dstudy", "ring4"])
+def test_spec_for_sends_what_the_cells_always_sent(config, mix, spec, line):
+    from repro.experiments.sweep import WindowSweep
+    from repro.service import wire
+    got = traffic.spec_for(_read("configs", config),
+                           _read("traffic", mix)["request"], 12345)
+    assert got == spec and list(got) == list(spec)
+    assert json.dumps(wire.encode_request(WindowSweep(**got),
+                                          "client0")) == line
+
+
+def test_sweep_fields_go_last_into_every_request_warmup_too():
+    config = dict(CONFIG, sweep_fields={"rd_mode": True})
+    specs = [r.spec for b in traffic.warmup_batches(TENANTS, config)
+             for r in b]
+    specs += [r.spec for r in traffic.sessions(TENANTS, config, 3, 40)]
+    specs += [r.spec for r in traffic.Source(STUDY, config, 3, 1.0)
+              .take_due(0.0)]
+    assert {r.kind for r in traffic.sessions(TENANTS, config, 3, 40)} == {
+        "study", "prefix", "duplicate", "longer"}
+    for spec in specs:
+        assert list(spec)[-1] == "rd_mode" and spec["rd_mode"] is True
+        plain = dict(spec)
+        del plain["rd_mode"]
+        assert list(plain) == list(traffic.spec_for(CONFIG, STUDY["request"],
+                                                    0))
+
+
+def test_a_sweep_field_that_the_harness_builds_is_refused():
+    config = dict(CONFIG, sweep_fields={"k_fuse": 32, "rd_mode": True})
+    with pytest.raises(traffic.BenchError, match=r"\['k_fuse'\]"):
+        traffic.spec_for(config, STUDY["request"], 0)
+
+
+def test_a_sweep_field_the_program_lacks_is_left_to_window_sweep():
+    from repro.experiments.sweep import WindowSweep
+    config = dict(CONFIG, sweep_fields={"coupling_J": 1.0})
+    spec = traffic.spec_for(config, STUDY["request"], 0)
+    assert spec["coupling_J"] == 1.0
+    with pytest.raises(TypeError, match="coupling_J"):
+        WindowSweep(**spec)
 
 
 def test_pe_steps_counts_rows_ring_and_steps():
